@@ -1,5 +1,11 @@
 """Kernel micro-benchmarks — the paper's "same performance" claim.
 
+CPU-only. Every number here is a host wall-time or a count on the CPU
+backend (Pallas in interpret mode); none is a device metric. Do not run it
+on a TPU: the ring bench starts a child process after this one has
+initialised JAX, and a child cannot reach a chip its parent holds. The
+on-chip check of the same paths is `chip_smoke.py`.
+
 The paper's hardware comparison holds THROUGHPUT EQUAL (same pipelined
 latency, same dataflow) and wins on area/power. The software analogues
 measured here:
@@ -265,6 +271,7 @@ smoke = bool(int(sys.argv[1]))
 from repro.core.attention import MaskSpec, flash_attention
 from repro.distributed.context import cp_decode, ring_prefill
 from repro.kernels.tuning import choose_ring_schedule
+from repro.launch.mesh import make_mesh
 
 def bench(fn, iters=3):
     jax.block_until_ready(fn())
@@ -275,7 +282,7 @@ def bench(fn, iters=3):
         best = min(best, time.perf_counter() - t0)
     return best
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(0)
 b, s, h, d = (1, 256, 2, 32) if smoke else (1, 2048, 4, 64)
 q = jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.float32)
@@ -507,7 +514,8 @@ def _bench_quant(report, smoke: bool) -> dict:
         f"({eng_q.peak_active} vs {eng_f.peak_active} peak sequences)")
 
     # --- accuracy: teacher-forced paged decode, int8 vs native pool
-    B, plen, steps, n_per = 2, 8, 6, 8
+    B, plen, steps, max_len = 2, 8, 6, 32
+    n_per = max_len // page  # the cache's table width
     toks_in = jnp.asarray(
         np.random.default_rng(99).integers(1, cfg.vocab_size, (B, plen)),
         jnp.int32)
@@ -515,7 +523,7 @@ def _bench_quant(report, smoke: bool) -> dict:
                        for b in range(B)], jnp.int32)
 
     def probe(kv_dtype, forced):
-        cache = init_decode_cache(B, 32, cfg, layout="paged", page_size=page,
+        cache = init_decode_cache(B, max_len, cfg, layout="paged", page_size=page,
                                   n_pages=1 + B * n_per, kv_dtype=kv_dtype)
 
         def set_tbl(path, x):

@@ -11,8 +11,8 @@ directly; collective bytes are parsed from the post-partitioning HLO text
 (`compiled.as_text()`) by summing operand sizes of all-gather / all-reduce /
 reduce-scatter / all-to-all / collective-permute ops (also per-device).
 
-Hardware constants (TPU v5e, assignment-specified):
-    197 TFLOP/s bf16 · 819 GB/s HBM · ~50 GB/s/link ICI.
+Hardware constants come from `PEAKS`, keyed by `device_kind` as JAX
+reports it. A kind not in the table is an error, never a default.
 """
 
 from __future__ import annotations
@@ -21,18 +21,35 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["HW", "collective_bytes", "roofline", "RooflineReport", "parse_hlo_collectives"]
-
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
-ICI_BW = 50e9  # bytes/s per link
+__all__ = [
+    "HW", "PEAKS", "peaks_for", "collective_bytes", "roofline",
+    "RooflineReport", "parse_hlo_collectives",
+]
 
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = PEAK_FLOPS
-    hbm_bw: float = HBM_BW
-    ici_bw: float = ICI_BW
+    peak_flops: float  # bf16 FLOP/s per chip
+    hbm_bw: float  # HBM bytes/s per chip
+    ici_bw: float  # bytes/s per ICI link
+
+
+# Published per-chip peaks (Google Cloud documentation, "TPU v5e"): 197
+# TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of interconnect over 4 links.
+PEAKS = {
+    "TPU v5 lite": HW(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> HW:
+    """Peaks of the chip JAX names `device_kind` (`Device.device_kind`)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 
 _DTYPE_BYTES = {
@@ -152,7 +169,7 @@ def roofline(
     cost: dict,
     hlo_text: str,
     model_flops: float,
-    hw: HW = HW(),
+    hw: HW,
     memory_per_device: Optional[dict] = None,
 ) -> RooflineReport:
     flops = float(cost.get("flops", 0.0))

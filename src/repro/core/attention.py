@@ -75,12 +75,16 @@ def _attention_core_fwd(q, k, v, mask, scale, impl, block_q, block_k, skip):
     hkv = k.shape[2]
     g = hq // hkv
     if impl.endswith("_pallas"):
+        from repro.distributed.sharding import batch_parallel  # lazy: no cycle
         from repro.kernels import ops as kernel_ops  # lazy: avoid import cycle
 
-        o, lam = kernel_ops.pallas_attention_fwd_batched(
-            q, k, v, mask=mask, scale=scale, impl=impl.replace("_pallas", ""),
-            block_q=block_q, block_k=block_k, skip=skip,
+        kernel = functools.partial(
+            kernel_ops.pallas_attention_fwd_batched, mask=mask, scale=scale,
+            impl=impl.replace("_pallas", ""), block_q=block_q,
+            block_k=block_k, skip=skip,
         )
+        # GSPMD cannot partition a Mosaic call: per batch shard under a mesh
+        o, lam = batch_parallel(kernel, q, k, v)
         return o, (q, k, v, o, lam.reshape(b, hkv, g, sq))
     # group queries on their shared KV head: [B, Hkv, G, Sq, d]
     qg = q.transpose(0, 2, 1, 3).reshape(b, hkv, g, sq, d)
@@ -109,17 +113,15 @@ def _attention_core_bwd(mask, scale, impl, block_q, block_k, skip, res, do):
     is ever saved by the forward."""
     q, k, v, o, lam = res
     b, sq, hq, _ = q.shape
+    from repro.distributed.sharding import batch_parallel  # lazy: no cycle
     from repro.kernels import ops as kernel_ops  # lazy: avoid import cycle
 
-    op = (
-        kernel_ops.get_op("attention_bwd")
-        if impl.endswith("_pallas")
-        else kernel_ops.get_fallback("attention_bwd")
-    )
-    return op(
-        q, k, v, o, lam.reshape(b, hq, sq), do,
-        mask=mask, scale=scale, impl=impl, block_q=block_q, block_k=block_k,
-    )
+    args = (q, k, v, o, lam.reshape(b, hq, sq), do)
+    kw = dict(mask=mask, scale=scale, impl=impl, block_q=block_q, block_k=block_k)
+    if impl.endswith("_pallas"):  # a Mosaic call: per batch shard under a mesh
+        op = functools.partial(kernel_ops.get_op("attention_bwd"), **kw)
+        return batch_parallel(op, *args)
+    return kernel_ops.get_fallback("attention_bwd")(*args, **kw)
 
 
 _attention_core.defvjp(
@@ -369,7 +371,7 @@ def varlen_attention(
             from repro.kernels.tuning import choose_varlen_blocks
 
             block_q = choose_varlen_blocks(
-                t, d, dv, group=g, page=page,
+                t, d, dv, group=g, kv_heads=hkv, page=page,
                 kv_itemsize=jnp.dtype(k_pages.dtype).itemsize
                 if k_scale is not None else 4,
             ).block_q

@@ -63,18 +63,11 @@ _CAUSAL_FAMILY = ("causal", "local", "chunked")
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (replication checks off: the pallas
-    calls and collectives inside have no registered replication rules)."""
-    if hasattr(jax, "shard_map"):  # jax ≥ 0.6
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-            )
-        except TypeError:  # pragma: no cover — kwarg drift
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+    """shard_map with replication checks off: the pallas calls and
+    collectives inside have no registered replication rules."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def _resolve_mesh(mesh: Optional[Mesh]) -> Mesh:
@@ -190,9 +183,9 @@ def ring_prefill(
     if scale is None:
         scale = float(1.0 / (q.shape[-1] ** 0.5))
     if interpret is None:
-        from repro.kernels.ops import on_tpu  # lazy: no cycle
+        from repro.kernels.ops import interpret_mode  # lazy: no cycle
 
-        interpret = not on_tpu()
+        interpret = interpret_mode()
     sq_sh, skv_sh = q.shape[1] // n, k.shape[1] // n
     if block_q is None or block_k is None:
         from repro.kernels.tuning import choose_ring_schedule  # lazy: no cycle
@@ -397,9 +390,9 @@ def cp_decode(
     if scale is None:
         scale = float(1.0 / (d ** 0.5))
     if interpret is None:
-        from repro.kernels.ops import on_tpu  # lazy: no cycle
+        from repro.kernels.ops import interpret_mode  # lazy: no cycle
 
-        interpret = not on_tpu()
+        interpret = interpret_mode()
     s_sh = s_max // n
     cache_len = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (b,))
 

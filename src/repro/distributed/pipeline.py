@@ -21,8 +21,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["pipeline_apply", "split_stages"]
 
@@ -89,11 +88,11 @@ def pipeline_apply(
         return jax.lax.psum(outs, axis_name)
 
     param_specs = jax.tree.map(lambda _: P(axis_name), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         stage_fn,
         mesh=mesh,
         in_specs=(param_specs, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)

@@ -34,10 +34,9 @@ __all__ = [
     "spec_for",
     "param_specs",
     "ShardingCtx",
-    "mesh_ctx",
-    "sharded_jit",
     "cp_axis_for_cache",
     "cp_batch_axes_for_cache",
+    "batch_parallel",
 ]
 
 _TL = threading.local()
@@ -102,59 +101,6 @@ def activate(ctx: Optional[ShardingCtx]):
         yield
     finally:
         _TL.ctx = prev
-
-
-def mesh_ctx(mesh: Mesh):
-    """Ambient-mesh context across jax versions.
-
-    jax ≥ 0.6 has `jax.set_mesh`; 0.5.x has `jax.sharding.use_mesh`; older
-    releases fall back to the legacy `with mesh:` context (which is what
-    lets `with_sharding_constraint` resolve bare PartitionSpecs)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
-def _resolve_shardings(tree, mesh: Mesh):
-    """PartitionSpec / None leaves → NamedSharding on `mesh`.
-
-    None ⇒ fully replicated, matching legacy pjit's in_axis_resources
-    semantics — which is what this fallback path targets. Note the
-    divergence from modern `jax.set_mesh` jit, where a None leaf stays
-    UNSPECIFIED and GSPMD may infer a sharding instead; older jax exposes
-    no public UNSPECIFIED sentinel, so replication is the faithful legacy
-    behavior."""
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s if isinstance(s, P) else P()),
-        tree,
-        is_leaf=lambda x: isinstance(x, P) or x is None,
-    )
-
-
-def sharded_jit(fn, *, in_shardings=None, out_shardings=None, mesh=None, **jit_kwargs):
-    """`jax.jit` that accepts PartitionSpec trees for shardings on any jax
-    version. Where `jax.set_mesh` exists, specs pass straight through (the
-    ambient mesh resolves them); otherwise they are resolved here against
-    `mesh` (default: the active ShardingCtx's mesh)."""
-    if not hasattr(jax, "set_mesh"):
-        if mesh is None:
-            ctx = active_ctx()
-            if ctx is None or ctx.mesh is None:
-                raise RuntimeError(
-                    "sharded_jit needs a mesh (argument or active ShardingCtx)"
-                )
-            mesh = ctx.mesh
-        if in_shardings is not None:
-            in_shardings = _resolve_shardings(in_shardings, mesh)
-        if out_shardings is not None:
-            out_shardings = _resolve_shardings(out_shardings, mesh)
-    if in_shardings is not None:
-        jit_kwargs["in_shardings"] = in_shardings
-    if out_shardings is not None:
-        jit_kwargs["out_shardings"] = out_shardings
-    return jax.jit(fn, **jit_kwargs)
 
 
 def _fit(ctx: ShardingCtx, dim_size: int, axes):
@@ -290,6 +236,30 @@ def spec_for(kind: str, shape: Sequence[int]) -> Optional[P]:
         b = _fit(ctx, shape[0], ctx.batch_axes + ("model",))
         return P(b, *([None] * (len(shape) - 1)))
     return _ACT_RULES[kind](ctx, tuple(shape))
+
+
+def batch_parallel(fn, *args):
+    """`fn(*args)` run on each device's share of the leading (batch) axis.
+
+    GSPMD cannot partition a Mosaic kernel, so under an active ShardingCtx
+    a Pallas call runs inside a shard_map: the batch is split over every
+    mesh axis that divides it (the full-DP attention layout of
+    `_heads_spec`) and whatever does not divide is replicated. With no
+    active context this is `fn(*args)`. Every operand and result must be
+    batch-leading."""
+    ctx = active_ctx()
+    if ctx is None or ctx.mesh is None:
+        return fn(*args)
+    axes = _fit(ctx, args[0].shape[0], ctx.batch_axes + ("model",))
+
+    def spec(x):
+        return P(axes, *([None] * (len(x.shape) - 1)))
+
+    out_specs = jax.tree.map(spec, jax.eval_shape(fn, *args))
+    return jax.shard_map(
+        fn, mesh=ctx.mesh, in_specs=tuple(spec(a) for a in args),
+        out_specs=out_specs, check_vma=False,
+    )(*args)
 
 
 def shard(x: jax.Array, kind: str) -> jax.Array:

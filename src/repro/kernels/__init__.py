@@ -12,7 +12,6 @@ ref           — pure-jnp oracles
 
 from repro.kernels.ops import (
     get_op,
-    on_tpu,
     op_names,
     pallas_attention_fwd_batched,
     pallas_decode,
@@ -23,7 +22,6 @@ from repro.kernels.ops import (
 
 __all__ = [
     "get_op",
-    "on_tpu",
     "op_names",
     "pallas_attention_fwd_batched",
     "pallas_decode",

@@ -21,16 +21,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.blockwise import MaskSpec, NEG_INF, tile_live
-from repro.kernels.flashd_fwd import _mask_bias
+from repro.kernels.flashd_fwd import _mask_bias, _tile_pos
 
 __all__ = ["fa2_fwd_pallas"]
 
@@ -56,8 +50,7 @@ def _fa2_kernel(
         m_scratch[...] = jnp.full_like(m_scratch, NEG_INF)
         l_scratch[...] = jnp.zeros_like(l_scratch)
 
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q,), 0)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k,), 0)
+    q_pos, k_pos = _tile_pos(iq, ik, block_q, block_k)
 
     # shared static tile pruning (one predicate for fwd/bwd/fa2 kernels)
     compute = tile_live(mask, iq, ik, block_q, block_k, kv_len)
@@ -72,30 +65,28 @@ def _fa2_kernel(
         keep = _mask_bias(mask, q_pos, k_pos, kv_len)
         s = jnp.where(keep, s, NEG_INF)
 
-        m_prev = m_scratch[0]
-        m_b = jnp.max(s, axis=-1)
+        m_prev = m_scratch[...]  # [B_q, 1]
+        m_b = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_b)  # serial cross-tile max chain
         m_safe = jnp.maximum(m_new, NEG_INF / 2)
         alpha = jnp.exp(m_prev - m_safe)
         alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
-        p = jnp.exp(s - m_safe[:, None])
-        l_new = l_scratch[0] * alpha + jnp.sum(p, axis=-1)
+        p = jnp.exp(s - m_safe)
+        l_new = l_scratch[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         v = v_ref[0, 0].astype(jnp.float32)
         pv = jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv  # rescale + accum
-        m_scratch[0] = m_new
-        l_scratch[0] = l_new
+        acc_ref[...] = acc_ref[...] * alpha + pv  # rescale + accum
+        m_scratch[...] = m_new
+        l_scratch[...] = l_new
 
     @pl.when(ik == n_kv_blocks - 1)
     def _finalize():
-        l = l_scratch[0]
+        l = l_scratch[...]
         l_safe = jnp.maximum(l, jnp.finfo(jnp.float32).tiny)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)  # epilogue div
-        lam_ref[0, 0] = jnp.where(
-            l > 0, m_scratch[0] + jnp.log(l_safe), NEG_INF
-        )
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)  # epilogue div
+        lam_ref[0, 0] = jnp.where(l > 0, m_scratch[...] + jnp.log(l_safe), NEG_INF)
 
 
 def fa2_fwd_pallas(
@@ -150,36 +141,27 @@ def fa2_fwd_pallas(
     ]
     out_specs = [
         pl.BlockSpec((1, 1, block_q, dv), lambda b_, h, iq, ik: (b_, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b_, h, iq, ik: (b_, h, iq)),
+        pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, iq, ik: (b_, h, iq, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((b, hq, sq + pad_q, dv), q.dtype),
-        jax.ShapeDtypeStruct((b, hq, sq + pad_q), jnp.float32),
+        jax.ShapeDtypeStruct((b, hq, sq + pad_q, 1), jnp.float32),
     ]
-    scratch_shapes = []
-    compiler_params = None
-    if _HAS_PLTPU:
-        scratch_shapes = [
-            pltpu.VMEM((block_q, dv), jnp.float32),
-            pltpu.VMEM((1, block_q), jnp.float32),
-            pltpu.VMEM((1, block_q), jnp.float32),
-        ]
-        try:
-            compiler_params = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-            )
-        except Exception:
-            compiler_params = None
-
     call = pl.pallas_call(
         kernel,
         grid=(b, hq, n_q, n_k),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=scratch_shapes,
+        scratch_shapes=[
+            pltpu.VMEM((block_q, dv), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+        ],
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+        ),
     )
     o, lam = call(q, k, v)
-    return o[:, :, :sq], lam[:, :, :sq]
+    return o[:, :, :sq], lam[:, :, :sq, 0]

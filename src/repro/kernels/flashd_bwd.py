@@ -25,32 +25,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.blockwise import MaskSpec, NEG_INF, tile_live
-from repro.kernels.flashd_fwd import _mask_bias
+from repro.kernels.flashd_fwd import _mask_bias, _tile_pos
 
 __all__ = ["flashd_bwd_pallas"]
 
 
 def _recompute_p_ds(q, k, v, do, lam, dsum, q_pos, k_pos, mask, scale, kv_len):
+    """P and dS of one (B_q, B_k) tile; lam, dsum and q_pos are [B_q, 1]."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
     keep = _mask_bias(mask, q_pos, k_pos, kv_len)
     s = jnp.where(keep, s, NEG_INF)
-    p = jnp.exp(s - lam[:, None])  # exponent ≤ 0 — overflow-free
-    p = jnp.where(lam[:, None] <= NEG_INF / 2, 0.0, p)
+    p = jnp.exp(s - lam)  # exponent ≤ 0 — overflow-free
+    p = jnp.where(lam <= NEG_INF / 2, 0.0, p)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    ds = p * (dp - dsum[:, None]) * scale
+    ds = p * (dp - dsum) * scale
     return p, ds
 
 
@@ -62,8 +57,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lam_ref, dsum_ref, dq_ref, acc_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q,), 0)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k,), 0)
+    q_pos, k_pos = _tile_pos(iq, ik, block_q, block_k)
 
     @pl.when(tile_live(mask, iq, ik, block_q, block_k, kv_len))
     def _body():
@@ -93,8 +87,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lam_ref, dsum_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q,), 0)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k,), 0)
+    q_pos, k_pos = _tile_pos(iq, ik, block_q, block_k)
 
     @pl.when(tile_live(mask, iq, ik, block_q, block_k, kv_len))
     def _body():
@@ -150,9 +143,14 @@ def flashd_bwd_pallas(
     n_q = (sq + pad_q) // block_q
     n_k = (skv + pad_k) // block_k
 
-    dsum = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # [B,Hq,Sq']
-
-    scr = (lambda shp: [pltpu.VMEM(shp, jnp.float32)]) if _HAS_PLTPU else (lambda shp: [])
+    # per-row statistics enter as [.., Sq', 1] columns: legal block dims
+    dsum = jnp.sum(
+        do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1, keepdims=True
+    )
+    lam = lam[..., None]
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+    )
 
     # ---- dQ ----
     dq_kernel = functools.partial(
@@ -167,13 +165,14 @@ def flashd_bwd_pallas(
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h, iq, ik, g=g: (b_, h // g, ik, 0)),
             pl.BlockSpec((1, 1, block_k, dv), lambda b_, h, iq, ik, g=g: (b_, h // g, ik, 0)),
             pl.BlockSpec((1, 1, block_q, dv), lambda b_, h, iq, ik: (b_, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h, iq, ik: (b_, h, iq)),
-            pl.BlockSpec((1, 1, block_q), lambda b_, h, iq, ik: (b_, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, iq, ik: (b_, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, iq, ik: (b_, h, iq, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d), lambda b_, h, iq, ik: (b_, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, sq + pad_q, d), q.dtype),
-        scratch_shapes=scr((block_q, d)),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        compiler_params=params,
     )(q, k, v, do, lam, dsum)
 
     # ---- dK, dV (q-group folded into the inner loop) ----
@@ -185,9 +184,6 @@ def flashd_bwd_pallas(
     def qhead(b_, h, ik, inner, g=g, n_q=n_q):
         return (b_, h * g + inner // n_q, inner % n_q, 0)
 
-    def qhead3(b_, h, ik, inner, g=g, n_q=n_q):
-        return (b_, h * g + inner // n_q, inner % n_q)
-
     dk, dv_out = pl.pallas_call(
         dkv_kernel,
         grid=(b, hkv, n_k, n_q * g),
@@ -196,8 +192,8 @@ def flashd_bwd_pallas(
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h, ik, inner: (b_, h, ik, 0)),
             pl.BlockSpec((1, 1, block_k, dv), lambda b_, h, ik, inner: (b_, h, ik, 0)),
             pl.BlockSpec((1, 1, block_q, dv), qhead),
-            pl.BlockSpec((1, 1, block_q), qhead3),
-            pl.BlockSpec((1, 1, block_q), qhead3),
+            pl.BlockSpec((1, 1, block_q, 1), qhead),
+            pl.BlockSpec((1, 1, block_q, 1), qhead),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h, ik, inner: (b_, h, ik, 0)),
@@ -207,8 +203,12 @@ def flashd_bwd_pallas(
             jax.ShapeDtypeStruct((b, hkv, skv + pad_k, d), k.dtype),
             jax.ShapeDtypeStruct((b, hkv, skv + pad_k, dv), v.dtype),
         ],
-        scratch_shapes=scr((block_k, d)) + scr((block_k, dv)),
+        scratch_shapes=[
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
+        ],
         interpret=interpret,
+        compiler_params=params,
     )(q, k, v, do, lam, dsum)
 
     return dq[:, :, :sq], dk[:, :, :skv], dv_out[:, :, :skv]

@@ -52,35 +52,40 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.blockwise import NEG_INF, merge_partials
 
 __all__ = ["flashd_decode_pallas", "flashd_decode_paged_pallas"]
 
+# Mosaic tiling: every block's last two dims are (8k, 128k) or the full
+# array dims, and every in-kernel vector is at least 2-D. Row statistics
+# (m, λ, Λ) are therefore [rows, 1] columns, and per-row scalars of the
+# grid (cache_len, start) ride in SMEM as scalar-prefetch operands.
+
 
 def _split_partial(cache_len, start, q, k, v, *, lo, split, window, chunk, scale):
-    """Per-split normalized partial (o_p [G, dv], λ_p [G]) — shared by the
-    fused, unfused and paged kernels so their per-split arithmetic is
+    """Per-split normalized partial (o_p [G, dv], λ_p [G, 1]) — shared by
+    the fused, unfused and paged kernels so their per-split arithmetic is
     identical. q [G, d], k [split, d], v [split, dv] (already f32)."""
     lo_bound = _lo_bound(cache_len, start, window=window, chunk=chunk)
-    pos = lo + jax.lax.broadcasted_iota(jnp.int32, (split,), 0)
+    pos = lo + jax.lax.broadcasted_iota(jnp.int32, (1, split), 1)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # [G, split]
     keep = jnp.logical_and(pos >= lo_bound, pos < cache_len)
-    s = jnp.where(keep[None, :], s, NEG_INF)
-    m = jnp.max(s, axis=-1)
+    s = jnp.where(keep, s, NEG_INF)
+    return _normalized_partial(s, v)
+
+
+def _normalized_partial(s, v):
+    """(softmax(s)·V, λ = logsumexp(s)) of one masked score tile s [R, n]
+    against v [n, dv]; fully-masked rows come back dead (λ = NEG_INF,
+    o = 0), the identity of the sigmoid merge."""
+    m = jnp.max(s, axis=-1, keepdims=True)
     m_safe = jnp.maximum(m, NEG_INF / 2)
-    p = jnp.exp(s - m_safe[:, None])
-    l = jnp.sum(p, axis=-1)
+    p = jnp.exp(s - m_safe)
+    l = jnp.sum(p, axis=-1, keepdims=True)
     lam = jnp.where(
         l > 0,
         m_safe + jnp.log(jnp.maximum(l, jnp.finfo(jnp.float32).tiny)),
@@ -90,7 +95,7 @@ def _split_partial(cache_len, start, q, k, v, *, lo, split, window, chunk, scale
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     c = jnp.where(l > 0, jnp.exp(m_safe - lam), 0.0)  # ⇒ pv·c = softmax·V
-    return pv * c[:, None], lam
+    return pv * c, lam
 
 
 def _lo_bound(cache_len, start, *, window: int, chunk: int):
@@ -113,22 +118,24 @@ def _split_live(cache_len, start, lo, split, *, window: int, chunk: int):
 def _merge_into_carry(o_p, lam_p, acc_ref, lam_scratch):
     """FLASH-D sigmoid merge of one partial into the VMEM carry — the same
     blend op as blockwise.merge_pair, applied sequentially along the
-    innermost grid axis. Shared by the fused and paged kernels."""
-    lam_run = lam_scratch[0]
+    innermost grid axis. Shared by the fused, paged and varlen kernels;
+    λ_p and the Λ carry are [rows, 1] columns."""
+    lam_run = lam_scratch[...]
     w = jax.nn.sigmoid(lam_p - lam_run)
     dead_b = lam_p <= NEG_INF / 2
     dead_a = lam_run <= NEG_INF / 2
     w = jnp.where(dead_b, 0.0, jnp.where(dead_a, 1.0, w))
     acc = acc_ref[...]
-    acc_ref[...] = acc + (o_p - acc) * w[:, None]
+    acc_ref[...] = acc + (o_p - acc) * w
     ln_w1 = jax.nn.log_sigmoid(lam_run - lam_p)  # ln(1−w)
-    lam_scratch[0] = jnp.where(
+    lam_scratch[...] = jnp.where(
         dead_b, lam_run, jnp.where(dead_a, lam_p, lam_run - ln_w1)
     )
 
 
 def _decode_fused_kernel(
-    cache_len_ref, start_ref, q_ref, k_ref, v_ref,
+    cache_len_ref, start_ref,  # scalar prefetch (SMEM)
+    q_ref, k_ref, v_ref,
     *refs,  # outputs (o [, λ]) then VMEM scratch (acc, Λ carry)
     split: int,
     n_splits: int,
@@ -141,9 +148,10 @@ def _decode_fused_kernel(
         o_ref, lam_ref, acc_ref, lam_scratch = refs
     else:
         (o_ref, acc_ref, lam_scratch), lam_ref = refs, None
+    ib = pl.program_id(0)
     ip = pl.program_id(2)  # innermost, sequential
-    cache_len = cache_len_ref[0, 0]
-    start = start_ref[0, 0]
+    cache_len = cache_len_ref[ib]
+    start = start_ref[ib]
     lo = ip * split
 
     @pl.when(ip == 0)
@@ -166,11 +174,12 @@ def _decode_fused_kernel(
     def _finalize():
         o_ref[0, 0] = acc_ref[...].astype(o_ref.dtype)
         if emit_lam:
-            lam_ref[0, 0] = lam_scratch[0]
+            lam_ref[0, 0] = lam_scratch[...]
 
 
 def _decode_unfused_kernel(
-    cache_len_ref, start_ref, q_ref, k_ref, v_ref,
+    cache_len_ref, start_ref,  # scalar prefetch (SMEM)
+    q_ref, k_ref, v_ref,
     o_ref, lam_ref,
     *,
     split: int,
@@ -178,9 +187,10 @@ def _decode_unfused_kernel(
     chunk: int,
     scale: float,
 ):
+    ib = pl.program_id(0)
     ip = pl.program_id(2)
-    cache_len = cache_len_ref[0, 0]
-    start = start_ref[0, 0]
+    cache_len = cache_len_ref[ib]
+    start = start_ref[ib]
     lo = ip * split
     live = _split_live(cache_len, start, lo, split, window=window, chunk=chunk)
 
@@ -193,8 +203,8 @@ def _decode_unfused_kernel(
             v_ref[0, 0].astype(jnp.float32),
             lo=lo, split=split, window=window, chunk=chunk, scale=scale,
         )
-        o_ref[0, 0, :, 0, :] = o_p.astype(o_ref.dtype)
-        lam_ref[0, 0, :, 0] = lam
+        o_ref[0, 0, 0] = o_p.astype(o_ref.dtype)
+        lam_ref[0, 0, 0] = lam
 
     @pl.when(jnp.logical_not(live))
     def _dead():
@@ -247,51 +257,52 @@ def flashd_decode_pallas(
     split = (s_max + pad) // n_splits
 
     qg = q.reshape(b, hkv, g, d)
-    cache_len = jnp.asarray(cache_len, jnp.int32).reshape(b, 1)
+    cache_len = jnp.asarray(cache_len, jnp.int32).reshape(b)
     if start is None:
-        start = jnp.zeros((b, 1), jnp.int32)
+        start = jnp.zeros((b,), jnp.int32)
     else:
-        start = jnp.asarray(start, jnp.int32).reshape(b, 1)
+        start = jnp.asarray(start, jnp.int32).reshape(b)
 
     in_specs = [
-        pl.BlockSpec((1, 1), lambda b_, h, ip: (b_, 0)),
-        pl.BlockSpec((1, 1), lambda b_, h, ip: (b_, 0)),
-        pl.BlockSpec((1, 1, g, d), lambda b_, h, ip: (b_, h, 0, 0)),
-        pl.BlockSpec((1, 1, split, d), lambda b_, h, ip: (b_, h, ip, 0)),
-        pl.BlockSpec((1, 1, split, dv), lambda b_, h, ip: (b_, h, ip, 0)),
+        pl.BlockSpec((1, 1, g, d), lambda b_, h, ip, cl, st: (b_, h, 0, 0)),
+        pl.BlockSpec((1, 1, split, d), lambda b_, h, ip, cl, st: (b_, h, ip, 0)),
+        pl.BlockSpec((1, 1, split, dv), lambda b_, h, ip, cl, st: (b_, h, ip, 0)),
     ]
     grid = (b, hkv, n_splits)
 
-    if fused and _HAS_PLTPU:
+    if fused:
         kernel = functools.partial(
             _decode_fused_kernel, split=split, n_splits=n_splits,
             window=window, chunk=chunk, scale=scale, emit_lam=return_lam,
         )
-        try:
-            compiler_params = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")
-            )
-        except Exception:  # older/newer API name drift
-            compiler_params = None
         # one output block revisited across splits — written once, at the
         # last split, from the VMEM carry: no per-split HBM partials
-        out_specs = [pl.BlockSpec((1, 1, g, dv), lambda b_, h, ip: (b_, h, 0, 0))]
+        out_specs = [
+            pl.BlockSpec((1, 1, g, dv), lambda b_, h, ip, cl, st: (b_, h, 0, 0))
+        ]
         out_shape = [jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype)]
         if return_lam:
-            out_specs.append(pl.BlockSpec((1, 1, g), lambda b_, h, ip: (b_, h, 0)))
-            out_shape.append(jax.ShapeDtypeStruct((b, hkv, g), jnp.float32))
+            out_specs.append(
+                pl.BlockSpec((1, 1, g, 1), lambda b_, h, ip, cl, st: (b_, h, 0, 0))
+            )
+            out_shape.append(jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32))
         call = pl.pallas_call(
             kernel,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_specs if return_lam else out_specs[0],
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=grid,
+                in_specs=in_specs,
+                out_specs=out_specs if return_lam else out_specs[0],
+                scratch_shapes=[
+                    pltpu.VMEM((g, dv), jnp.float32),
+                    pltpu.VMEM((g, 1), jnp.float32),
+                ],
+            ),
             out_shape=out_shape if return_lam else out_shape[0],
-            scratch_shapes=[
-                pltpu.VMEM((g, dv), jnp.float32),
-                pltpu.VMEM((1, g), jnp.float32),
-            ],
             interpret=interpret,
-            **({"compiler_params": compiler_params} if compiler_params else {}),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")
+            ),
         )
         out = call(cache_len, start, qg, k_cache, v_cache)
         if return_lam:
@@ -302,26 +313,31 @@ def flashd_decode_pallas(
     kernel = functools.partial(
         _decode_unfused_kernel, split=split, window=window, chunk=chunk, scale=scale
     )
-    out_specs = [
-        pl.BlockSpec((1, 1, g, 1, dv), lambda b_, h, ip: (b_, h, 0, ip, 0)),
-        pl.BlockSpec((1, 1, g, 1), lambda b_, h, ip: (b_, h, 0, ip)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((b, hkv, g, n_splits, dv), jnp.float32),
-        jax.ShapeDtypeStruct((b, hkv, g, n_splits), jnp.float32),
-    ]
     call = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec(
+                    (1, 1, 1, g, dv), lambda b_, h, ip, cl, st: (b_, h, ip, 0, 0)
+                ),
+                pl.BlockSpec(
+                    (1, 1, 1, g, 1), lambda b_, h, ip, cl, st: (b_, h, ip, 0, 0)
+                ),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hkv, n_splits, g, dv), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, n_splits, g, 1), jnp.float32),
+        ],
         interpret=interpret,
     )
     o_p, lam_p = call(cache_len, start, qg, k_cache, v_cache)
     # FLASH-D sigmoid merge over splits (axis moved to front for the tree)
-    o_p = jnp.moveaxis(o_p, 3, 0)  # [P, B, Hkv, G, dv]
-    lam_p = jnp.moveaxis(lam_p, 3, 0)
+    o_p = jnp.moveaxis(o_p, 2, 0)  # [P, B, Hkv, G, dv]
+    lam_p = jnp.moveaxis(lam_p[..., 0], 2, 0)
     o, lam = merge_partials(o_p, lam_p)
     o = o.reshape(b, hq, dv).astype(q.dtype)
     if return_lam:
@@ -349,7 +365,7 @@ def _decode_paged_kernel(
     else:
         (o_ref, acc_ref, lam_scratch), ks_ref, vs_ref = refs, None, None
     ib = pl.program_id(0)
-    ip = pl.program_id(2)  # logical page index — innermost, sequential
+    ip = pl.program_id(1)  # logical page index — innermost, sequential
     cache_len = cache_len_ref[ib]
     start = jnp.int32(0)
     lo = ip * page
@@ -361,20 +377,38 @@ def _decode_paged_kernel(
 
     @pl.when(_split_live(cache_len, start, lo, page, window=window, chunk=chunk))
     def _body():
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [page, d] — gathered page
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:  # dequant in-tile: one per-(page, head) f32 scale each
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
-        o_p, lam_p = _split_partial(
-            cache_len, start, q_ref[0, 0].astype(jnp.float32), k, v,
-            lo=lo, split=page, window=window, chunk=chunk, scale=scale,
-        )
-        _merge_into_carry(o_p, lam_p, acc_ref, lam_scratch)
+        for h in range(k_ref.shape[2]):  # static: Mosaic slices heads statically
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # [page, d]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:  # dequant in-tile: one per-(page, head) f32 scale each
+                k = k * ks_ref[0, :, h:h + 1]
+                v = v * vs_ref[0, :, h:h + 1]
+            o_p, lam_p = _split_partial(
+                cache_len, start, q_ref[0, h].astype(jnp.float32), k, v,
+                lo=lo, split=page, window=window, chunk=chunk, scale=scale,
+            )
+            _merge_into_carry(o_p, lam_p, acc_ref.at[h], lam_scratch.at[h])
 
     @pl.when(ip == n_tbl - 1)
     def _finalize():
-        o_ref[0, 0] = acc_ref[...].astype(o_ref.dtype)
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+
+
+def page_scale_blocks(k_scale, v_scale):
+    """Kernel views of a quantized pool's per-(page, head) scales.
+
+    A grid step fetches one whole page, all kv heads: the [P, page, Hkv, d]
+    pool is read unreshaped as (1, page, Hkv, d) blocks, whose trailing
+    dims equal the array's for every head width (a one-head (page, d)
+    block tiles only when d is a multiple of 128, and a reshape of the pool
+    would copy it on every call). Scales [P, Hkv] become [P, 1, Hkv] so the
+    page's (1, Hkv) row is such a block too. () for a native pool."""
+    if k_scale is None:
+        return ()
+    return tuple(
+        jnp.asarray(sc, jnp.float32).reshape(sc.shape[0], 1, sc.shape[1])
+        for sc in (k_scale, v_scale)
+    )
 
 
 def flashd_decode_paged_pallas(
@@ -393,27 +427,23 @@ def flashd_decode_paged_pallas(
 ):
     """Fused FLASH-D decode over a paged KV cache → o [B, Hq, dv].
 
-    Grid (B, Hkv, N) with the logical-page axis innermost and sequential;
+    Grid (B, N) with the logical-page axis innermost and sequential;
     `block_tbl` and `cache_len` are scalar-prefetch operands, so the K/V
     BlockSpec index maps resolve `tbl[b, ip]` *before* the step's DMA is
     issued — the kernel never sees the indirection, each step's K/V block
-    is one physical page, and the (acc, Λ) carry merges pages with the same
-    one-sigmoid-one-FMA blend as the contiguous fused kernel. Table slots
-    past the live region may hold anything (engine convention: garbage page
-    0) — their pages are DMA'd but `pl.when`-skipped, like padded splits.
+    is one physical page (all kv heads), and every head's (acc, Λ) carry
+    merges pages with the same one-sigmoid-one-FMA blend as the contiguous
+    fused kernel. Table slots past the live region may hold anything
+    (engine convention: garbage page 0) — their pages are DMA'd but
+    `pl.when`-skipped, like padded splits.
 
     With `k_scale`/`v_scale` the pool is quantized (runtime/quant.py,
     DESIGN.md §3.8): the same index maps fetch the page's per-head f32
-    scale as a (1, 1) block and the tile is dequantized right after its
-    upcast, before the scores — nothing downstream of the multiply changes.
-
-    Without pltpu (non-TPU install), falls back to a jnp gather of the
-    table followed by the contiguous fused kernel — same math, the gather
-    (and dequant) materialized in HBM instead of hidden in the DMA
-    descriptors.
+    scales and each head's tile is dequantized right after its upcast,
+    before the scores — nothing downstream of the multiply changes.
     """
     b, hq, d = q.shape
-    p_pool, page, hkv, dv = v_pages.shape
+    _, page, hkv, dv = v_pages.shape
     n_tbl = block_tbl.shape[1]
     g = hq // hkv
     if scale is None:
@@ -423,18 +453,7 @@ def flashd_decode_paged_pallas(
     quantized = k_scale is not None
     block_tbl = jnp.asarray(block_tbl, jnp.int32)
     cache_len = jnp.asarray(cache_len, jnp.int32).reshape(b)
-
-    if not _HAS_PLTPU:  # pragma: no cover — jax without pallas TPU support
-        kg, vg_ = k_pages[block_tbl], v_pages[block_tbl]  # [B, N, page, Hkv, ·]
-        if quantized:
-            kg = kg.astype(jnp.float32) * k_scale[block_tbl][:, :, None, :, None]
-            vg_ = vg_.astype(jnp.float32) * v_scale[block_tbl][:, :, None, :, None]
-        kc = jnp.moveaxis(kg, 3, 1).reshape(b, hkv, n_tbl * page, d)
-        vc = jnp.moveaxis(vg_, 3, 1).reshape(b, hkv, n_tbl * page, dv)
-        return flashd_decode_pallas(
-            q, kc, vc, cache_len, scale=scale, n_splits=n_tbl, window=window,
-            chunk=chunk, fused=True, interpret=interpret,
-        )
+    scales = page_scale_blocks(k_scale, v_scale)
 
     qg = q.reshape(b, hkv, g, d)
     kernel = functools.partial(
@@ -442,47 +461,35 @@ def flashd_decode_paged_pallas(
         chunk=chunk, scale=scale, quantized=quantized,
     )
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda b_, h, ip, tbl, cl: (b_, h, 0, 0)),
+        pl.BlockSpec((1, hkv, g, d), lambda b_, ip, tbl, cl: (b_, 0, 0, 0)),
         # the physical page: logical page ip of row b_ through the table
-        pl.BlockSpec(
-            (1, page, 1, d), lambda b_, h, ip, tbl, cl: (tbl[b_, ip], 0, h, 0)
-        ),
-        pl.BlockSpec(
-            (1, page, 1, dv), lambda b_, h, ip, tbl, cl: (tbl[b_, ip], 0, h, 0)
-        ),
+        pl.BlockSpec((1, page, hkv, d), lambda b_, ip, tbl, cl: (tbl[b_, ip], 0, 0, 0)),
+        pl.BlockSpec((1, page, hkv, dv), lambda b_, ip, tbl, cl: (tbl[b_, ip], 0, 0, 0)),
     ]
     if quantized:  # per-(page, head) scales ride the same table indirection
         in_specs += [
-            pl.BlockSpec((1, 1), lambda b_, h, ip, tbl, cl: (tbl[b_, ip], h)),
-            pl.BlockSpec((1, 1), lambda b_, h, ip, tbl, cl: (tbl[b_, ip], h)),
-        ]
+            pl.BlockSpec((1, 1, hkv), lambda b_, ip, tbl, cl: (tbl[b_, ip], 0, 0))
+        ] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, n_tbl),
+        grid=(b, n_tbl),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, 1, g, dv), lambda b_, h, ip, tbl, cl: (b_, h, 0, 0)
+            (1, hkv, g, dv), lambda b_, ip, tbl, cl: (b_, 0, 0, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((g, dv), jnp.float32),
-            pltpu.VMEM((1, g), jnp.float32),
+            pltpu.VMEM((hkv, g, dv), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
         ],
     )
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except Exception:  # older/newer API name drift
-        compiler_params = None
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
     )
-    args = (block_tbl, cache_len, qg, k_pages, v_pages)
-    if quantized:
-        args += (jnp.asarray(k_scale, jnp.float32), jnp.asarray(v_scale, jnp.float32))
-    o = call(*args)
+    o = call(block_tbl, cache_len, qg, k_pages, v_pages, *scales)
     return o.reshape(b, hq, dv)

@@ -32,33 +32,36 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific bits are optional so the module imports on CPU hosts
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.blockwise import MaskSpec, NEG_INF, DEFAULT_SKIP_THETA, tile_live
 
 __all__ = ["flashd_fwd_pallas"]
 
 
+def _tile_pos(iq, ik, block_q: int, block_k: int):
+    """Absolute (q_pos [B_q, 1], k_pos [1, B_k]) of tile (iq, ik) — 2-D, as
+    Mosaic wants every in-kernel vector."""
+    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    return q_pos, k_pos
+
+
 def _mask_bias(mask: MaskSpec, q_pos, k_pos, kv_len: int):
-    """In-kernel additive bias for a (B_q, B_k) tile; None if fully visible."""
-    keep = k_pos[None, :] < kv_len  # mask padded keys
+    """In-kernel keep-mask for a (B_q, B_k) tile from q_pos [B_q, 1] and
+    k_pos [1, B_k]."""
+    keep = k_pos < kv_len  # mask padded keys
     if mask.kind != "full":
-        qp = (q_pos + mask.q_offset)[:, None]
-        kp = k_pos[None, :]
+        qp = q_pos + mask.q_offset
         if mask.kind == "causal":
-            keep = keep & (kp <= qp)
+            keep = keep & (k_pos <= qp)
         elif mask.kind == "local":
-            keep = keep & (kp <= qp) & (qp - kp < mask.window)
+            keep = keep & (k_pos <= qp) & (qp - k_pos < mask.window)
         elif mask.kind == "chunked":
-            keep = keep & (kp <= qp) & (qp // mask.chunk == kp // mask.chunk)
+            keep = keep & (k_pos <= qp) & (qp // mask.chunk == k_pos // mask.chunk)
         else:
             raise ValueError(mask.kind)
     return keep
@@ -87,8 +90,7 @@ def _flashd_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
         lam_scratch[...] = jnp.full_like(lam_scratch, NEG_INF)
 
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q,), 0)
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_k,), 0)
+    q_pos, k_pos = _tile_pos(iq, ik, block_q, block_k)
 
     # static tile pruning: tiles fully outside the mask never compute;
     # fully-padded q tiles (from pad_q) have no live rows: skip their whole
@@ -108,13 +110,14 @@ def _flashd_kernel(
         keep = _mask_bias(mask, q_pos, k_pos, kv_len)
         s = jnp.where(keep, s, NEG_INF)
 
-        m_b = jnp.max(s, axis=-1)  # tile-LOCAL max; no cross-tile chain
-        lam_run = lam_scratch[0]
+        m_b = jnp.max(s, axis=-1, keepdims=True)  # tile-LOCAL max; no chain
+        lam_run = lam_scratch[...]  # [B_q, 1]
+        skip_bound = -(skip_theta + float(np.log(block_k)))
 
         def _update():
             m_safe = jnp.maximum(m_b, NEG_INF / 2)
-            p = jnp.exp(s - m_safe[:, None])
-            l_b = jnp.sum(p, axis=-1)
+            p = jnp.exp(s - m_safe)
+            l_b = jnp.sum(p, axis=-1, keepdims=True)
             lam_b = jnp.where(
                 l_b > 0,
                 m_safe + jnp.log(jnp.maximum(l_b, jnp.finfo(jnp.float32).tiny)),
@@ -135,24 +138,20 @@ def _flashd_kernel(
                 p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
             )
             if skip:  # per-row predication (exact at any θ by construction)
-                row_skip = jnp.logical_and(
-                    m_b - lam_run < -(skip_theta + jnp.log(jnp.float32(block_k))),
-                    ~first,
-                )
+                row_skip = jnp.logical_and(m_b - lam_run < skip_bound, ~first)
                 w = jnp.where(row_skip, 0.0, w)
                 c = jnp.where(row_skip, 0.0, c)
                 lam_new = jnp.where(row_skip, lam_run, lam_new)
-            acc_ref[...] = acc_ref[...] * (1.0 - w)[:, None] + pv * c[:, None]
-            lam_scratch[0] = lam_new
+            acc_ref[...] = acc_ref[...] * (1.0 - w) + pv * c
+            lam_scratch[...] = lam_new
 
         if skip:
             # whole-tile skip: every row below threshold ⇒ no exp, no MXU
             # matmul, no blend. This is the FLOP-level win on TPU.
-            any_live = jnp.any(
-                m_b - lam_run >= -(skip_theta + jnp.log(jnp.float32(block_k)))
-            )
-            first_any = jnp.any(lam_run <= NEG_INF / 2)
-            pl.when(jnp.logical_or(any_live, first_any))(_update)
+            n_live = jnp.sum(jnp.logical_or(
+                m_b - lam_run >= skip_bound, lam_run <= NEG_INF / 2
+            ).astype(jnp.float32))
+            pl.when(n_live > 0)(_update)
         else:
             _update()
 
@@ -160,7 +159,7 @@ def _flashd_kernel(
     def _finalize():
         # No division, no rescale: acc already holds softmax(S)·V exactly.
         o_ref[0, 0] = acc_ref[...].astype(o_ref.dtype)
-        lam_ref[0, 0] = lam_scratch[0]
+        lam_ref[0, 0] = lam_scratch[...]
 
 
 def flashd_fwd_pallas(
@@ -223,37 +222,29 @@ def flashd_fwd_pallas(
         pl.BlockSpec((1, 1, block_k, d), lambda b_, h, iq, ik, g=group: (b_, h // g, ik, 0)),
         pl.BlockSpec((1, 1, block_k, dv), lambda b_, h, iq, ik, g=group: (b_, h // g, ik, 0)),
     ]
+    # Λ leaves as a [.., Sq, 1] column: one f32 per row, legal block dims
     out_specs = [
         pl.BlockSpec((1, 1, block_q, dv), lambda b_, h, iq, ik: (b_, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b_, h, iq, ik: (b_, h, iq)),
+        pl.BlockSpec((1, 1, block_q, 1), lambda b_, h, iq, ik: (b_, h, iq, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((b, hq, sq + pad_q, dv), q.dtype),
-        jax.ShapeDtypeStruct((b, hq, sq + pad_q), jnp.float32),
+        jax.ShapeDtypeStruct((b, hq, sq + pad_q, 1), jnp.float32),
     ]
-    scratch_shapes = None
-    compiler_params = None
-    if _HAS_PLTPU:
-        scratch_shapes = [
-            pltpu.VMEM((block_q, dv), jnp.float32),
-            pltpu.VMEM((1, block_q), jnp.float32),
-        ]
-        try:
-            compiler_params = pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
-            )
-        except Exception:  # older/newer API name drift
-            compiler_params = None
-
     call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=scratch_shapes or [],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, dv), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+        ],
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+        ),
     )
     o, lam = call(q, k, v)
-    return o[:, :, :sq], lam[:, :, :sq]
+    return o[:, :, :sq], lam[:, :, :sq, 0]

@@ -22,9 +22,12 @@ Packing contract (the scheduler's packer enforces it):
     in its sequence's KV space, −1 for padding. Padding rows mask every key
     (q_pos −1 defeats the causal test) and come back as zero rows.
 
-Grid (q_block, kv_head, logical_page) — the page axis innermost and
-sequential. Per-block metadata (`blk_seq` = seq_ids[::block_q], which is
-exact under the alignment contract) plus `kv_len` and the block table are
+Grid (q_block, logical_page) — the page axis innermost and sequential;
+each step's K/V block is one whole physical page, all kv heads, and the
+body walks the heads in a static loop. Per-block metadata (`blk_seq` =
+seq_ids[::block_q], which is exact under the alignment contract, and the
+block's largest and smallest q_pos for tile pruning) plus `kv_len` and
+the block table are
 scalar-prefetch operands: the K/V BlockSpec index maps resolve
 `tbl[blk_seq[ib], ip]` before each step's DMA is issued, so the page
 gather lives in the DMA descriptors exactly like the paged decode kernel.
@@ -33,9 +36,10 @@ partial, and the in-VMEM (acc, Λ) sigmoid carry — merged with
 `_merge_into_carry`, unchanged. Masks are per-element (sequence boundary ×
 causal × window/chunk), so tile pruning is purely a FLOP optimization.
 
-Without pltpu (non-TPU install) the jnp mirror in
-`repro.core.attention.varlen_attention` provides the same math; this
-module's fallback just routes there.
+Kernel layout: q is viewed head-major, [Hkv, G, T, d], so one grid step's
+q block is the (Hkv, G, block_q, d) slab and each kv head's G·block_q
+rows collapse into one score matmul without a relayout; q_pos enters as
+a [T, 1] column, one position per row.
 """
 
 from __future__ import annotations
@@ -47,54 +51,39 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU-specific bits are optional so the module imports on CPU hosts
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.blockwise import NEG_INF
-from repro.kernels.flashd_decode import _merge_into_carry
+from repro.kernels.flashd_decode import (
+    _merge_into_carry,
+    _normalized_partial,
+    page_scale_blocks,
+)
 
 __all__ = ["flashd_varlen_pallas"]
 
 
 def _varlen_partial(q, k, q_pos, kv_len, lo, *, page, window, chunk, scale, v):
-    """Normalized partial (o_p [R, dv], λ_p [R]) of R packed query rows
+    """Normalized partial (o_p [R, dv], λ_p [R, 1]) of R packed query rows
     against one gathered page. Per-row masks: key visible iff it is inside
     the row's sequence (< kv_len), causally visible (≤ q_pos), and inside
     the window/chunk structure. Rows with q_pos < 0 (padding) see nothing
-    and come back dead (λ = NEG_INF ⇒ identity under the sigmoid merge)."""
-    pos = lo + jax.lax.broadcasted_iota(jnp.int32, (page,), 0)
+    and come back dead (λ = NEG_INF ⇒ identity under the sigmoid merge).
+    q_pos is the [R, 1] column of row positions."""
+    pos = lo + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # [R, page]
-    keep = jnp.logical_and(pos[None, :] < kv_len, pos[None, :] <= q_pos[:, None])
+    keep = jnp.logical_and(pos < kv_len, pos <= q_pos)
     if window > 0:
-        keep = jnp.logical_and(keep, q_pos[:, None] - pos[None, :] < window)
+        keep = jnp.logical_and(keep, q_pos - pos < window)
     if chunk > 0:
-        keep = jnp.logical_and(keep, q_pos[:, None] // chunk == pos[None, :] // chunk)
-    s = jnp.where(keep, s, NEG_INF)
-    m = jnp.max(s, axis=-1)
-    m_safe = jnp.maximum(m, NEG_INF / 2)
-    p = jnp.exp(s - m_safe[:, None])
-    l = jnp.sum(p, axis=-1)
-    lam = jnp.where(
-        l > 0,
-        m_safe + jnp.log(jnp.maximum(l, jnp.finfo(jnp.float32).tiny)),
-        NEG_INF,
-    )
-    pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    c = jnp.where(l > 0, jnp.exp(m_safe - lam), 0.0)
-    return pv * c[:, None], lam
+        keep = jnp.logical_and(keep, q_pos // chunk == pos // chunk)
+    return _normalized_partial(jnp.where(keep, s, NEG_INF), v)
 
 
 def _varlen_kernel(
-    blk_seq_ref, kv_len_ref, tbl_ref,  # scalar prefetch (SMEM)
+    blk_seq_ref, blk_qmax_ref, blk_qmin_ref, kv_len_ref, tbl_ref,  # SMEM
     q_ref, qpos_ref, k_ref, v_ref,  # VMEM (k/v: the gathered physical page)
     *refs,  # quantized: (ks, vs) scale blocks; then o, then VMEM carry
     block_q: int,
@@ -111,7 +100,7 @@ def _varlen_kernel(
     else:
         (o_ref, acc_ref, lam_scratch), ks_ref, vs_ref = refs, None, None
     ib = pl.program_id(0)
-    ip = pl.program_id(2)  # logical page — innermost, sequential
+    ip = pl.program_id(1)  # logical page — innermost, sequential
     seq_raw = blk_seq_ref[ib]
     seq = jnp.maximum(seq_raw, 0)
     kv_len = jnp.where(seq_raw >= 0, kv_len_ref[seq], 0)
@@ -122,38 +111,40 @@ def _varlen_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
         lam_scratch[...] = jnp.full_like(lam_scratch, NEG_INF)
 
-    q_pos = qpos_ref[0]  # [block_q]
-    q_max = jnp.max(q_pos)
     # conservative tile pruning: per-element masks above are exact, this
     # only skips pages no row of the block can see (future pages under the
     # causal test, pages past the sequence end). Padding rows carry
-    # q_pos = −1, which can only shrink q_max — never un-prune a live page.
+    # q_pos = −1, which can only shrink the block's max — never un-prune a
+    # live page.
     live = jnp.logical_and(
-        seq_raw >= 0, jnp.logical_and(lo < kv_len, lo <= q_max)
+        seq_raw >= 0, jnp.logical_and(lo < kv_len, lo <= blk_qmax_ref[ib])
     )
     if window > 0:
-        live = jnp.logical_and(live, lo + page > jnp.min(q_pos) - window + 1)
+        live = jnp.logical_and(live, lo + page > blk_qmin_ref[ib] - window + 1)
 
     @pl.when(live)
     def _body():
-        q = q_ref[:, 0].astype(jnp.float32).reshape(block_q * group, -1)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:  # dequant in-tile: one per-(page, head) f32 scale each
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
-        o_p, lam_p = _varlen_partial(
-            q, k,
-            jnp.repeat(q_pos, group),
-            kv_len, lo, page=page, window=window, chunk=chunk, scale=scale,
-            v=v,
-        )
-        _merge_into_carry(o_p, lam_p, acc_ref, lam_scratch)
+        d = q_ref.shape[-1]
+        q_pos = jnp.concatenate([qpos_ref[...]] * group, axis=0)  # [G·bq, 1]
+        for h in range(k_ref.shape[2]):  # static: Mosaic slices heads statically
+            q = q_ref[h].astype(jnp.float32).reshape(group * block_q, d)
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # [page, d]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:  # dequant in-tile: one per-(page, head) f32 scale each
+                k = k * ks_ref[0, :, h:h + 1]
+                v = v * vs_ref[0, :, h:h + 1]
+            o_p, lam_p = _varlen_partial(
+                q, k, q_pos, kv_len, lo, page=page, window=window,
+                chunk=chunk, scale=scale, v=v,
+            )
+            _merge_into_carry(o_p, lam_p, acc_ref.at[h], lam_scratch.at[h])
 
     @pl.when(ip == n_tbl - 1)
     def _finalize():
-        dv = o_ref.shape[-1]
-        o_ref[:, 0] = acc_ref[...].reshape(block_q, group, dv).astype(o_ref.dtype)
+        hkv, _, dv = acc_ref.shape
+        o_ref[...] = acc_ref[...].reshape(hkv, group, block_q, dv).astype(
+            o_ref.dtype
+        )
 
 
 def flashd_varlen_pallas(
@@ -201,92 +192,55 @@ def flashd_varlen_pallas(
     q_pos = jnp.asarray(q_pos, jnp.int32)
     kv_len = jnp.asarray(kv_len, jnp.int32).reshape(-1)
     blk_seq = seq_ids[::block_q]  # exact under the alignment contract
+    blk_pos = q_pos.reshape(nb, block_q)
+    scales = page_scale_blocks(k_scale, v_scale)
 
-    if not _HAS_PLTPU:  # pragma: no cover — jax without pallas TPU support
-        from repro.core.attention import varlen_attention
-
-        return varlen_attention(
-            q, k_pages, v_pages, block_tbl, seq_ids, q_pos, kv_len,
-            scale=scale, window=window, chunk=chunk, impl="flashd",
-            k_scale=k_scale, v_scale=v_scale,
-        )
-
-    qg = q.reshape(t, hkv, g, d)
-    qpos2 = q_pos.reshape(nb, block_q)
+    qg = q.reshape(t, hkv, g, d).transpose(1, 2, 0, 3)  # [Hkv, G, T, d]
 
     kernel = functools.partial(
         _varlen_kernel, block_q=block_q, group=g, page=page, n_tbl=n_tbl,
         window=window, chunk=chunk, scale=scale, quantized=quantized,
     )
-    in_specs = [
-        pl.BlockSpec(
-            (block_q, 1, g, d),
-            lambda ib, h, ip, bs, kl, tbl: (ib, h, 0, 0),
-        ),
-        pl.BlockSpec((1, block_q), lambda ib, h, ip, bs, kl, tbl: (ib, 0)),
+
+    def page_of(ib, ip, bs, qmax, qmin, kl, tbl):
         # the physical page: logical page ip of the block's sequence,
         # resolved through the table in the DMA descriptor
-        pl.BlockSpec(
-            (1, page, 1, d),
-            lambda ib, h, ip, bs, kl, tbl: (
-                tbl[jnp.maximum(bs[ib], 0), ip], 0, h, 0
-            ),
-        ),
-        pl.BlockSpec(
-            (1, page, 1, dv),
-            lambda ib, h, ip, bs, kl, tbl: (
-                tbl[jnp.maximum(bs[ib], 0), ip], 0, h, 0
-            ),
-        ),
+        return tbl[jnp.maximum(bs[ib], 0), ip]
+
+    in_specs = [
+        pl.BlockSpec((hkv, g, block_q, d), lambda ib, *_: (0, 0, ib, 0)),
+        pl.BlockSpec((block_q, 1), lambda ib, *_: (ib, 0)),
+        pl.BlockSpec((1, page, hkv, d), lambda *a: (page_of(*a), 0, 0, 0)),
+        pl.BlockSpec((1, page, hkv, dv), lambda *a: (page_of(*a), 0, 0, 0)),
     ]
     if quantized:  # per-(page, head) scales ride the same table indirection
         in_specs += [
-            pl.BlockSpec(
-                (1, 1),
-                lambda ib, h, ip, bs, kl, tbl: (
-                    tbl[jnp.maximum(bs[ib], 0), ip], h
-                ),
-            ),
-            pl.BlockSpec(
-                (1, 1),
-                lambda ib, h, ip, bs, kl, tbl: (
-                    tbl[jnp.maximum(bs[ib], 0), ip], h
-                ),
-            ),
-        ]
+            pl.BlockSpec((1, 1, hkv), lambda *a: (page_of(*a), 0, 0))
+        ] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(nb, hkv, n_tbl),
+        num_scalar_prefetch=5,
+        grid=(nb, n_tbl),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (block_q, 1, g, dv), lambda ib, h, ip, bs, kl, tbl: (ib, h, 0, 0)
+            (hkv, g, block_q, dv), lambda ib, *_: (0, 0, ib, 0)
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q * g, dv), jnp.float32),
-            pltpu.VMEM((1, block_q * g), jnp.float32),
+            pltpu.VMEM((hkv, g * block_q, dv), jnp.float32),
+            pltpu.VMEM((hkv, g * block_q, 1), jnp.float32),
         ],
     )
-    try:
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-    except Exception:  # older/newer API name drift
-        compiler_params = None
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, hkv, g, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((hkv, g, t, dv), q.dtype),
         interpret=interpret,
-        **({"compiler_params": compiler_params} if compiler_params else {}),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
     )
-    args = (
-        blk_seq, kv_len, jnp.asarray(block_tbl, jnp.int32),
-        qg, qpos2, k_pages, v_pages,
+    o = call(
+        blk_seq, blk_pos.max(axis=1), blk_pos.min(axis=1), kv_len,
+        jnp.asarray(block_tbl, jnp.int32),
+        qg, q_pos.reshape(t, 1), k_pages, v_pages, *scales,
     )
-    if quantized:
-        args += (
-            jnp.asarray(k_scale, jnp.float32),
-            jnp.asarray(v_scale, jnp.float32),
-        )
-    o = call(*args)
-    return o.reshape(t, hq, dv)
+    return o.transpose(2, 0, 1, 3).reshape(t, hq, dv)

@@ -1,9 +1,10 @@
 """jit'd dispatch registry over the Pallas kernels.
 
-On TPU backends the real kernels run; everywhere else they execute in
-Pallas interpret mode (kernel body evaluated op-by-op on CPU) so every code
-path is exercised in CI. The models never import kernels directly — they go
-through `repro.core.attention`, which lands here for the `*_pallas` impls.
+On TPU backends the real kernels run; on the CPU backend they execute in
+Pallas interpret mode (kernel body evaluated op-by-op) so every code path
+is exercised in CI. Any other backend is an error, not a fallback. The
+models never import kernels directly — they go through
+`repro.core.attention`, which lands here for the `*_pallas` impls.
 
 The entry points form a REGISTRY: each is registered under a stable op
 name (`attention_fwd`, `decode`, `decode_paged`, `varlen`) so new kernel
@@ -21,6 +22,7 @@ of a serve; dynamic callers can swap a single op the same way.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict
 
 import jax
@@ -47,7 +49,7 @@ __all__ = [
     "register_fallback",
     "get_fallback",
     "fallback_impl",
-    "on_tpu",
+    "interpret_mode",
 ]
 
 _REGISTRY: Dict[str, Callable] = {}
@@ -109,15 +111,10 @@ def fallback_impl(attn_impl: str) -> str:
     return attn_impl[: -len(suffix)] if attn_impl.endswith(suffix) else attn_impl
 
 
-def on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
-def _interpret() -> bool:
-    return not on_tpu()
+def interpret_mode() -> bool:
+    """Pallas interpret mode exactly when the backend is the CPU (how the
+    tests run); any other backend compiles the kernels for real."""
+    return jax.default_backend() == "cpu"
 
 
 @register_op("attention_fwd")
@@ -134,21 +131,17 @@ def pallas_attention_fwd_batched(
     skip: bool,
 ):
     """Returns (o [B,Sq,Hq,dv], Λ [B,Hq,Sq]) — kernel layout handled here."""
-    qt = q.transpose(0, 2, 1, 3)  # [B, Hq, Sq, d]
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
     if impl == "flashd":
-        o, lam = flashd_fwd_pallas(
-            qt, kt, vt, mask=mask, scale=scale, block_q=block_q,
-            block_k=block_k, skip=skip, interpret=_interpret(),
-        )
+        kernel = functools.partial(flashd_fwd_pallas, skip=skip)
     elif impl == "fa2":
-        o, lam = fa2_fwd_pallas(
-            qt, kt, vt, mask=mask, scale=scale, block_q=block_q,
-            block_k=block_k, interpret=_interpret(),
-        )
+        kernel = fa2_fwd_pallas
     else:
         raise ValueError(f"unknown pallas impl {impl!r}")
+    o, lam = kernel(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), mask=mask, scale=scale, block_q=block_q,
+        block_k=block_k, interpret=interpret_mode(),
+    )
     return o.transpose(0, 2, 1, 3), lam
 
 
@@ -177,19 +170,13 @@ def pallas_attention_bwd_batched(
     Returns (dq, dk, dv) in model layout."""
     del impl  # one bwd kernel serves every fwd impl that saves Λ
     from repro.kernels.flashd_bwd import flashd_bwd_pallas  # lazy: keep import cheap
-
+    t = lambda x: x.transpose(0, 2, 1, 3)
     dq, dk, dv = flashd_bwd_pallas(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), o.transpose(0, 2, 1, 3),
-        lam, do.transpose(0, 2, 1, 3),
+        t(q), t(k), t(v), t(o), lam, t(do),
         mask=mask, scale=scale, block_q=block_q, block_k=block_k,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
-    return (
-        dq.transpose(0, 2, 1, 3),
-        dk.transpose(0, 2, 1, 3),
-        dv.transpose(0, 2, 1, 3),
-    )
+    return t(dq), t(dk), t(dv)
 
 
 @register_op("decode")
@@ -215,7 +202,7 @@ def pallas_decode(
         window=window,
         chunk=chunk,
         fused=fused,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
     return o[:, None]  # [B, 1, Hq, dv]
 
@@ -251,7 +238,7 @@ def pallas_decode_paged(
         chunk=chunk,
         k_scale=k_scale,
         v_scale=v_scale,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
     return o[:, None]  # [B, 1, Hq, dv]
 
@@ -285,7 +272,7 @@ def pallas_varlen(
         jnp.asarray(kv_len, jnp.int32).reshape(-1),
         scale=scale, window=window, chunk=chunk, block_q=block_q,
         k_scale=k_scale, v_scale=v_scale,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
 
 

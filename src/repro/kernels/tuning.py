@@ -368,22 +368,23 @@ class VarlenBlocks:
 
 def varlen_vmem_bytes(
     block_q: int, page: int, d: int, dv: int, group: int,
-    *, kv_itemsize: int = 4,
+    *, kv_heads: int = 1, kv_itemsize: int = 4,
 ) -> int:
     """Working set of one varlen grid step: q + k + v + carry + scores.
-    The q tile carries `group` heads per row (GQA rows collapse into the
-    score matmul), the KV block is one page — stored at `kv_itemsize`
-    bytes per element (1 when the page pool is quantized, plus the
-    two-scalar f32 scale side-band)."""
+    A step covers all `kv_heads`: the q tile carries `group` query heads
+    per row and kv head (GQA rows collapse into the score matmul), the KV
+    block is one whole page — stored at `kv_itemsize` bytes per element (1
+    when the page pool is quantized, plus the f32 scale side-band). The
+    heads are walked one at a time, so one score tile is live."""
     rows = block_q * group
     f32_words = (
-        rows * d          # q tile
-        + rows * dv       # acc carry
-        + rows            # Λ carry
-        + rows * page     # score tile
+        kv_heads * rows * d     # q tile
+        + kv_heads * rows * dv  # acc carry
+        + kv_heads * rows       # Λ carry
+        + rows * page           # score tile
     )
-    kv_words = page * d + page * dv  # k page + v page
-    side_band = 2 * 4 if kv_itemsize < 4 else 0  # k/v page scales
+    kv_words = kv_heads * page * (d + dv)  # k page + v page
+    side_band = 2 * 4 * kv_heads if kv_itemsize < 4 else 0  # page scales
     return 4 * f32_words + kv_itemsize * kv_words + side_band
 
 
@@ -393,6 +394,7 @@ def choose_varlen_blocks(
     dv: Optional[int] = None,
     *,
     group: int = 1,
+    kv_heads: int = 1,
     page: int = 64,
     segment_hint: Optional[int] = None,
     vmem_budget: int = VMEM_BUDGET_BYTES,
@@ -416,8 +418,10 @@ def choose_varlen_blocks(
     hint = max(min(segment_hint or total_tokens, total_tokens), 1)
     block_q = min(128, bucket_pow2(hint, lo=_MIN_BLOCK))
     while (
-        varlen_vmem_bytes(block_q, page, d, dv, group, kv_itemsize=kv_itemsize)
-        > vmem_budget
+        varlen_vmem_bytes(
+            block_q, page, d, dv, group, kv_heads=kv_heads,
+            kv_itemsize=kv_itemsize,
+        ) > vmem_budget
         and block_q > _MIN_BLOCK
     ):
         block_q = max(_MIN_BLOCK, block_q // 2)

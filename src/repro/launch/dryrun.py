@@ -43,6 +43,9 @@ from repro.train.train_step import TrainConfig, TrainState, make_train_step
 from repro.optim import OptState
 
 
+# the chip the production meshes are made of (roofline peaks, `device_kind`)
+TARGET_KIND = "TPU v5 lite"
+
 # Per-arch memory-fit knobs for the 256-chip/16GB-HBM pod, recorded in the
 # cell records: microbatch accumulation bounds live activations; bf16 Adam
 # m/v halves optimizer state (math stays f32). Serve cells load bf16 weights
@@ -171,14 +174,14 @@ def _compile_once(arch, shape_name, mesh, *, attn_impl=None, remat=None,
     ctx = shd.ShardingCtx(mesh, fsdp_axis=fsdp, use_sp=use_sp)
     ctx.tp_activations = use_tp
     with shd.activate(ctx):
-        with shd.mesh_ctx(mesh):
+        with jax.set_mesh(mesh):
             step, args, in_specs, out_specs, donate, model_flops, cfg = build_cell(
                 arch, shape_name, attn_impl=attn_impl, remat=remat,
                 extra_cfg=extra_cfg, train_overrides=train_overrides,
             )
-            jitted = shd.sharded_jit(step, in_shardings=in_specs,
-                                     out_shardings=out_specs,
-                                     donate_argnums=donate)
+            jitted = jax.jit(step, in_shardings=in_specs,
+                             out_shardings=out_specs,
+                             donate_argnums=donate)
             lowered = jitted.lower(*args)
             compiled = lowered.compile()
 
@@ -267,6 +270,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *, attn_impl=None,
              remat=None, extra_cfg=None, verbose=True, probe_cost=True,
              train_overrides=None, use_sp=True, use_tp=True):
     mesh = make_production_mesh(multi_pod=multi_pod)
+    hw = rf.peaks_for(TARGET_KIND)
     mesh_name = "2x16x16" if multi_pod else "16x16"
     chips = mesh.devices.size
     cfg0 = configs.get_config(arch)
@@ -297,9 +301,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *, attn_impl=None,
             bytes_per_device=cost["bytes accessed"],
             collective_bytes_per_device=cbytes,
             collectives={k: v for k, v in coll.items() if v["count"]},
-            t_compute=cost["flops"] / rf.PEAK_FLOPS,
-            t_memory=cost["bytes accessed"] / rf.HBM_BW,
-            t_collective=cbytes / rf.ICI_BW,
+            t_compute=cost["flops"] / hw.peak_flops,
+            t_memory=cost["bytes accessed"] / hw.hbm_bw,
+            t_collective=cbytes / hw.ici_bw,
             dominant="",
             model_flops=model_flops,
             useful_flops_ratio=0.0,
@@ -314,7 +318,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *, attn_impl=None,
     else:
         report = rf.roofline(
             arch=arch, shape=shape_name, mesh_name=mesh_name, chips=chips,
-            cost=cost_raw, hlo_text=hlo, model_flops=model_flops,
+            cost=cost_raw, hlo_text=hlo, model_flops=model_flops, hw=hw,
             memory_per_device=mem,
         )
 

@@ -18,12 +18,14 @@ per-request lifecycle budgets, and every request's terminal status
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.cache import enable_compile_cache
 from repro.models import get_model
 from repro.runtime import checkpoint as ckpt
 from repro.serve import Engine, ServeConfig
@@ -48,6 +50,7 @@ def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="paper-llama")
     p.add_argument("--smoke", action="store_true")
+    p.add_argument("--attn-impl", default=None)
     p.add_argument("--ckpt-dir", default=None, help="restore trained weights")
     p.add_argument("--requests", type=int, default=6)
     p.add_argument("--prompt-len", type=int, default=12)
@@ -64,9 +67,9 @@ def main(argv=None):
     p.add_argument("--kv-pool-tokens", type=int, default=0,
                    help="paged pool size in tokens (0 → max_batch·max_len)")
     p.add_argument("--kv-dtype", default="",
-                   help='quantize the paged KV pool: "int8" (or "fp8" '
-                        'where the host jax supports it) stores pages at '
-                        '1 B/elem with a per-page scale side-band — ~4x '
+                   help='quantize the paged KV pool: "int8" (or "fp8") '
+                        'stores pages at 1 B/elem with a per-page scale '
+                        'side-band — ~4x '
                         'the tokens per byte of HBM (DESIGN.md §3.8); '
                         'requires --kv-layout paged; "" → native dtype')
     p.add_argument("--step-mode", choices=("sequential", "mixed"),
@@ -116,7 +119,10 @@ def main(argv=None):
                         "set (quick A/B against the same command line)")
     args = p.parse_args(argv)
 
+    enable_compile_cache()
     cfg = configs.get_smoke_config(args.arch) if args.smoke else configs.get_config(args.arch)
+    if args.attn_impl:
+        cfg = dataclasses.replace(cfg, attn_impl=args.attn_impl)
     api = get_model(cfg)
     params = api.init(jax.random.PRNGKey(args.seed), cfg)
     if args.ckpt_dir:

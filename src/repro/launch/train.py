@@ -34,11 +34,61 @@ from jax.sharding import PartitionSpec as P
 from repro import configs
 from repro.data import DataConfig, SyntheticLM
 from repro.distributed import sharding as shd
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.optim import AdamWConfig, CompressionConfig, OptState
 from repro.runtime import checkpoint as ckpt
 from repro.runtime.resilience import FaultInjector, StragglerMonitor, plan_mesh
 from repro.train import ResilienceConfig, train_resilient
 from repro.train.train_step import TrainConfig, TrainState, init_train_state, make_train_step
+
+
+def build_trainer(cfg, tc: TrainConfig, *, seed: int, mesh=None):
+    """(init_state_fn, step_fn) for `train_resilient`: a fresh TrainState
+    and the jitted step, sharded by the rules engine over `mesh` (None →
+    one device). The step donates its input state: the supervisor never
+    reuses a state it has stepped, and keeping both would double the
+    parameter and optimizer memory."""
+    if mesh is None:
+        return (
+            lambda: init_train_state(jax.random.PRNGKey(seed), cfg, tc),
+            jax.jit(make_train_step(cfg, tc), donate_argnums=(0,)),
+        )
+    ctx = shd.ShardingCtx(mesh)
+
+    def _sspec(state):
+        pspecs = shd.param_specs(state.params)
+        return TrainState(params=pspecs,
+                          opt=OptState(m=pspecs, v=pspecs, step=P()),
+                          residual=(pspecs if state.residual is not None else None),
+                          step=P(), loss_scale=P(), good_steps=P(), skipped=P())
+
+    with shd.activate(ctx), jax.set_mesh(mesh):
+        shapes = jax.eval_shape(
+            lambda: init_train_state(jax.random.PRNGKey(seed), cfg, tc)
+        )
+        sspec = _sspec(shapes)
+        init = jax.jit(
+            lambda: init_train_state(jax.random.PRNGKey(seed), cfg, tc),
+            out_shardings=shd.to_named(sspec),
+        )
+        # out_shardings pins the state's layout: left to the partitioner,
+        # a leaf can come back sharded differently (qwen2's stacked q bias
+        # does on v5e), and the next step then refuses its own output
+        inner = jax.jit(
+            make_train_step(cfg, tc), in_shardings=(sspec, None),
+            out_shardings=(sspec, None), donate_argnums=(0,),
+        )
+
+    def init_state_fn():
+        with shd.activate(ctx), jax.set_mesh(mesh):
+            return init()
+
+    def step_fn(state, batch):
+        with shd.activate(ctx), jax.set_mesh(mesh):
+            return inner(state, batch)
+
+    return init_state_fn, step_fn
 
 
 def main(argv=None):
@@ -73,6 +123,7 @@ def main(argv=None):
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
 
+    enable_compile_cache()
     cfg = configs.get_smoke_config(args.arch) if args.smoke else configs.get_config(args.arch)
     if args.attn_impl:
         cfg = dataclasses.replace(cfg, attn_impl=args.attn_impl)
@@ -99,41 +150,11 @@ def main(argv=None):
         vocab_size=cfg.vocab_size, seq_len=args.seq_len,
         global_batch=args.global_batch, seed=args.seed,
     ))
-
+    mesh = None
     if n_dev > 1:
         plan = plan_mesh(n_dev)
-        mesh = jax.make_mesh(plan.mesh_shape, plan.axis_names)
-        ctx = shd.ShardingCtx(mesh)
-    else:
-        mesh = ctx = None
-
-    def init_state_fn():
-        state = init_train_state(jax.random.PRNGKey(args.seed), cfg, tc)
-        if ctx is not None:
-            with shd.activate(ctx), shd.mesh_ctx(mesh):
-                state = jax.device_put(state, shd.to_named(_sspec(state)))
-        return state
-
-    def _sspec(state):
-        pspecs = shd.param_specs(state.params)
-        return TrainState(params=pspecs,
-                          opt=OptState(m=pspecs, v=pspecs, step=P()),
-                          residual=(pspecs if state.residual is not None else None),
-                          step=P(), loss_scale=P(), good_steps=P(), skipped=P())
-
-    def build_step_fn():
-        step_raw = make_train_step(cfg, tc)
-        if ctx is None:
-            return jax.jit(step_raw)
-        with shd.activate(ctx), shd.mesh_ctx(mesh):
-            sspec = _sspec(init_state_fn())
-            inner = shd.sharded_jit(step_raw, in_shardings=(sspec, None))
-
-        def step(state, batch):
-            with shd.activate(ctx), shd.mesh_ctx(mesh):
-                return inner(state, batch)
-
-        return step
+        mesh = make_mesh(plan.mesh_shape, plan.axis_names)
+    init_state_fn, step_fn = build_trainer(cfg, tc, seed=args.seed, mesh=mesh)
 
     injector = None
     if args.fault_rate > 0:
@@ -174,7 +195,7 @@ def main(argv=None):
     state, history, counters = train_resilient(
         ckpt_dir=ckpt_dir, model_cfg=cfg, train_cfg=tc, data=data,
         total_steps=args.steps, seed=args.seed, res=res, injector=injector,
-        init_state_fn=init_state_fn, step_fn=build_step_fn(),
+        init_state_fn=init_state_fn, step_fn=step_fn,
         on_step=on_step,
     )
     print(

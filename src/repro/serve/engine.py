@@ -120,9 +120,9 @@ class ServeConfig:
     page_size: int = 0  # 0 → repro.kernels.tuning heuristic
     kv_pool_tokens: int = 0  # pool size in tokens; 0 → max_batch·max_len
     # quantized page pool (DESIGN.md §3.8): "" keeps the compute dtype;
-    # a name from repro.runtime.quant.available() ("int8", and "fp8" where
-    # the host jax has float8) stores pages in that format with per-(page,
-    # head) f32 scale leaves, dequantized inside the attention kernels
+    # a name from repro.runtime.quant.available() ("int8", "fp8") stores
+    # pages in that format with per-(page, head) f32 scale leaves,
+    # dequantized inside the attention kernels
     kv_dtype: str = ""
     # prefix reuse: `prefix_sharing` is the soundness gate (global-attn
     # stacks only — auto-disabled on hybrid stacks), `prefix_cache` the
@@ -368,9 +368,7 @@ class Engine:
 
         stack = contextlib.ExitStack()
         stack.enter_context(shd.activate(self.ctx))
-        mctx = shd.mesh_ctx(self.ctx.mesh)
-        if hasattr(mctx, "__enter__"):
-            stack.enter_context(mctx)
+        stack.enter_context(jax.set_mesh(self.ctx.mesh))
         return stack
 
     def _to_host(self, x) -> np.ndarray:
@@ -1234,7 +1232,8 @@ class Engine:
             spec_block_q = choose_varlen_blocks(
                 bucket_pow2(spec_budget, lo=8),
                 self.mc.head_dim_, self.mc.head_dim_,
-                group=self.mc.n_heads // self.mc.n_kv_heads, page=page,
+                group=self.mc.n_heads // self.mc.n_kv_heads,
+                kv_heads=self.mc.n_kv_heads, page=page,
                 segment_hint=self._spec.k + 1,
             ).block_q
 
@@ -1478,7 +1477,8 @@ class Engine:
                     else (1 if b > 1 else pchunk))
         block_q = choose_varlen_blocks(
             bucket_pow2(budget, lo=8), hd, hd,
-            group=self.mc.n_heads // self.mc.n_kv_heads, page=page,
+            group=self.mc.n_heads // self.mc.n_kv_heads,
+            kv_heads=self.mc.n_kv_heads, page=page,
             segment_hint=seg_hint,
         ).block_q
 

@@ -63,6 +63,13 @@ except ImportError:
         def deco(fn):
             @functools.wraps(fn)
             def wrapper(*args, **kwargs):
+                for drawn in getattr(wrapper, "_stub_examples", ()):
+                    try:
+                        fn(*args, **drawn, **kwargs)
+                    except Exception as e:
+                        raise AssertionError(
+                            f"falsifying example (stub, pinned): {drawn}"
+                        ) from e
                 n = getattr(wrapper, "_stub_max_examples", _DEFAULT_MAX_EXAMPLES)
                 seed = int.from_bytes(
                     hashlib.sha256(fn.__qualname__.encode()).digest()[:4], "big"
@@ -99,8 +106,16 @@ except ImportError:
 
         return deco
 
+    def example(**drawn):
+        def deco(fn):
+            fn._stub_examples = getattr(fn, "_stub_examples", ()) + (drawn,)
+            return fn
+
+        return deco
+
     _hyp = types.ModuleType("hypothesis")
     _hyp.given = given
+    _hyp.example = example
     _hyp.settings = settings
     _hyp.strategies = _st
     sys.modules["hypothesis"] = _hyp

@@ -51,10 +51,11 @@ def _run_in_subprocess(code: str):
 def test_ring_prefill_matches_single_device():
     _run_in_subprocess("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.core.attention import MaskSpec, flash_attention
     from repro.distributed.context import ring_prefill
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(0)
     b, s, hq, hkv, d = 1, 64, 4, 2, 16
     q = jnp.asarray(rng.normal(size=(b, s, hq, d)), jnp.float32)
@@ -82,11 +83,12 @@ def test_ring_prefill_wire_contract():
     [S, S] score-sized collectives — and structured masks prune hops."""
     _run_in_subprocess("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.core.attention import MaskSpec
     from repro.distributed.context import ring_prefill
     from repro.kernels.tuning import choose_ring_schedule
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(1)
     b, s, hq, hkv, d = 1, 64, 4, 2, 16
     n, s_sh = 8, 64 // 8
@@ -124,10 +126,11 @@ def test_ring_prefill_wire_contract():
 def test_cp_decode_matches_ref_ragged():
     _run_in_subprocess("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.distributed.context import cp_decode
     from repro.kernels.ref import decode_ref
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(2)
     b, hq, hkv, S, d = 4, 8, 2, 64, 16
     q = jnp.asarray(rng.normal(size=(b, hq, d)), jnp.float32)
@@ -167,11 +170,12 @@ def test_attention_api_cp_routing():
     exactly when the ShardingCtx kv_cache rule seq-shards the operands."""
     _run_in_subprocess("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.core.attention import MaskSpec, decode_attention, flash_attention
     from repro.distributed import sharding as shd
     from repro.kernels.ref import decode_ref
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     rng = np.random.default_rng(3)
     b, s, hq, hkv, d = 1, 64, 4, 2, 16
     q = jnp.asarray(rng.normal(size=(b, s, hq, d)), jnp.float32)
@@ -180,7 +184,7 @@ def test_attention_api_cp_routing():
     o_ref = flash_attention(q, k, v, mask=MaskSpec("causal"), impl="flashd")
 
     ctx = shd.ShardingCtx(mesh, cp_prefill=True)
-    with shd.activate(ctx), shd.mesh_ctx(mesh):
+    with shd.activate(ctx), jax.set_mesh(mesh):
         assert shd.cp_axis_for_cache(k.shape) == "data"
         o = flash_attention(q, k, v, mask=MaskSpec("causal"), impl="flashd")
         jx = str(jax.make_jaxpr(lambda *a: flash_attention(
@@ -189,7 +193,7 @@ def test_attention_api_cp_routing():
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                rtol=1e-4, atol=1e-5)
     # cp_prefill defaults OFF: same ctx without the flag keeps GSPMD path
-    with shd.activate(shd.ShardingCtx(mesh)), shd.mesh_ctx(mesh):
+    with shd.activate(shd.ShardingCtx(mesh)), jax.set_mesh(mesh):
         jx_off = str(jax.make_jaxpr(lambda *a: flash_attention(
             *a, mask=MaskSpec("causal"), impl="flashd"))(q, k, v))
     assert "ppermute" not in jx_off
@@ -203,7 +207,7 @@ def test_attention_api_cp_routing():
     cl = jnp.asarray([40, 0], jnp.int32)
     o_ref = decode_ref(qd[:, 0], kc.transpose(0, 2, 1, 3),
                        vc.transpose(0, 2, 1, 3), cl)
-    with shd.activate(shd.ShardingCtx(mesh)), shd.mesh_ctx(mesh):
+    with shd.activate(shd.ShardingCtx(mesh)), jax.set_mesh(mesh):
         o = decode_attention(qd, kc, vc, cl)
         jx = str(jax.make_jaxpr(lambda *a: decode_attention(*a))(qd, kc, vc, cl))
     assert "ppermute" in jx
@@ -220,11 +224,12 @@ def test_cp_decode_batch_and_seq_sharded_mesh():
     and still match the reference."""
     _run_in_subprocess("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.core.attention import decode_attention
     from repro.distributed import sharding as shd
     from repro.kernels.ref import decode_ref
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(5)
     b, hq, hkv, S, d = 2, 6, 2, 64, 16  # hkv=2 % model=4 != 0 ⇒ seq CP
     q = jnp.asarray(rng.normal(size=(b, 1, hq, d)), jnp.float32)
@@ -233,7 +238,7 @@ def test_cp_decode_batch_and_seq_sharded_mesh():
     cl = jnp.asarray([64, 11], jnp.int32)
     o_ref = decode_ref(q[:, 0], kc.transpose(0, 2, 1, 3),
                        vc.transpose(0, 2, 1, 3), cl)
-    with shd.activate(shd.ShardingCtx(mesh)), shd.mesh_ctx(mesh):
+    with shd.activate(shd.ShardingCtx(mesh)), jax.set_mesh(mesh):
         assert shd.cp_axis_for_cache(kc.shape) == "model"
         assert shd.cp_batch_axes_for_cache(kc.shape) == ("data",)
         o = decode_attention(q, kc, vc, cl)
@@ -252,6 +257,7 @@ def test_paged_decode_routes_through_cp_when_seq_sharded():
     _run_in_subprocess("""
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import paper_llama
     from repro.distributed import sharding as shd
     from repro.models import get_model
@@ -276,8 +282,8 @@ def test_paged_decode_routes_through_cp_when_seq_sharded():
     logits_ref, cache_ref = prefill_lm(
         params, jnp.asarray(prompts, jnp.int32), cache, cfg)
 
-    mesh = jax.make_mesh((8,), ("data",))
-    with shd.activate(shd.ShardingCtx(mesh)), shd.mesh_ctx(mesh):
+    mesh = make_mesh((8,), ("data",))
+    with shd.activate(shd.ShardingCtx(mesh)), jax.set_mesh(mesh):
         # gathered paged cache is [B=2, 64, 2, 8]: B < data ⇒ seq CP
         assert shd.cp_axis_for_cache((b, max_len, 2, 8)) == "data"
         logits_cp, _ = prefill_lm(
@@ -300,6 +306,7 @@ def test_engine_decode_on_cp_mesh_matches_unsharded():
     _run_in_subprocess("""
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.configs import paper_llama
     from repro.distributed import sharding as shd
     from repro.models import get_model
@@ -316,7 +323,7 @@ def test_engine_decode_on_cp_mesh_matches_unsharded():
 
     toks_ref = Engine(params, cfg, sc).generate(prompts, 8)
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     ctx = shd.ShardingCtx(mesh)  # B=2 < 8 ⇒ seq-sharded caches ⇒ cp_decode
     eng = Engine(params, cfg, sc, sharding_ctx=ctx)
     toks = eng.generate(prompts, 8)

@@ -40,10 +40,11 @@ def _run_in_subprocess(code: str):
 def test_pipeline_matches_sequential():
     _run_in_subprocess("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.distributed.pipeline import pipeline_apply, split_stages
 
     S, L, M, mb, d = 4, 8, 8, 4, 16
-    mesh = jax.make_mesh((S,), ("pod",))
+    mesh = make_mesh((S,), ("pod",))
     rng = np.random.default_rng(0)
     layer_w = jnp.asarray(rng.normal(size=(L, d, d)) * 0.3, jnp.float32)
     x = jnp.asarray(rng.normal(size=(M, mb, d)), jnp.float32)
@@ -69,10 +70,11 @@ def test_pipeline_matches_sequential():
 def test_pipeline_grads_flow():
     _run_in_subprocess("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.distributed.pipeline import pipeline_apply, split_stages
 
     S, L, M, mb, d = 2, 4, 4, 2, 8
-    mesh = jax.make_mesh((S,), ("pod",))
+    mesh = make_mesh((S,), ("pod",))
     rng = np.random.default_rng(1)
     layer_w = jnp.asarray(rng.normal(size=(L, d, d)) * 0.3, jnp.float32)
     x = jnp.asarray(rng.normal(size=(M, mb, d)), jnp.float32)
@@ -104,6 +106,7 @@ def test_sharded_train_step_matches_single_device():
     _run_in_subprocess("""
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     from repro import configs
     from repro.distributed import sharding as shd
@@ -120,9 +123,9 @@ def test_sharded_train_step_matches_single_device():
     sref, mref = jax.jit(make_train_step(cfg, tc))(state0, batch)
 
     # 4x2 (data, model) mesh with full rules engine
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ctx = shd.ShardingCtx(mesh)
-    with shd.activate(ctx), shd.mesh_ctx(mesh):
+    with shd.activate(ctx), jax.set_mesh(mesh):
         state = init_train_state(jax.random.PRNGKey(0), cfg, tc)
         pspecs = shd.param_specs(state.params)
         from repro.train.train_step import TrainState
@@ -132,8 +135,8 @@ def test_sharded_train_step_matches_single_device():
                            loss_scale=P(), good_steps=P(), skipped=P())
         state = jax.device_put(state, shd.to_named(sspec))
         batch_sh = jax.device_put(batch, shd.to_named(shd.batch_specs(batch)))
-        step = shd.sharded_jit(make_train_step(cfg, tc),
-                               in_shardings=(sspec, shd.batch_specs(batch)))
+        step = jax.jit(make_train_step(cfg, tc),
+                       in_shardings=(sspec, shd.batch_specs(batch)))
         s1, m1 = step(state, batch_sh)
 
     np.testing.assert_allclose(float(mref["loss"]), float(m1["loss"]), rtol=1e-4)
@@ -143,9 +146,51 @@ def test_sharded_train_step_matches_single_device():
     """)
 
 
+@pytest.mark.parametrize("attn_impl", ["flashd", "flashd_pallas"])
+def test_launcher_trainer_sharded_matches_single_device(attn_impl):
+    """`launch.train.build_trainer` on the plan_mesh(4) mesh == one device:
+    the launcher's sharded init and two steps, the second fed the state the
+    first returned (as the train loop does), with the Pallas kernels run
+    per batch shard inside a shard_map (GSPMD cannot partition them)."""
+    _run_in_subprocess(f"""
+    import dataclasses
+    import jax, jax.numpy as jnp, numpy as np
+    from repro import configs
+    from repro.data import DataConfig, SyntheticLM
+    from repro.launch.mesh import make_mesh
+    from repro.launch.train import build_trainer
+    from repro.runtime.resilience import plan_mesh
+    from repro.train.train_step import TrainConfig
+
+    cfg = dataclasses.replace(configs.get_smoke_config("qwen2-1.5b"),
+                              attn_impl="{attn_impl}")
+    tc = TrainConfig()
+    data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size, seq_len=16, global_batch=4))
+    batch = jax.tree.map(jnp.asarray, data.batch(0))
+
+    init1, step1 = build_trainer(cfg, tc, seed=0)
+    plan = plan_mesh(4)
+    mesh = make_mesh(plan.mesh_shape, plan.axis_names, devices=jax.devices()[:4])
+    init4, step4 = build_trainer(cfg, tc, seed=0, mesh=mesh)
+
+    sref, s4 = init1(), init4()
+    for _ in range(2):
+        sref, mref = step1(sref, batch)
+        s4, m4 = step4(s4, batch)
+        np.testing.assert_allclose(float(mref["loss"]), float(m4["loss"]), rtol=1e-4)
+    # the step hands back the state in the layout it takes it in
+    for a, b in zip(jax.tree.leaves(init4()), jax.tree.leaves(s4)):
+        assert a.sharding.is_equivalent_to(b.sharding, a.ndim), (a.sharding, b.sharding)
+    for a, b in zip(jax.tree.leaves(sref.params), jax.tree.leaves(s4.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3, atol=2e-4)
+    print("launcher sharded == single OK")
+    """)
+
+
 def test_moe_expert_parallel_matches():
     _run_in_subprocess("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     from repro import configs
     from repro.distributed import sharding as shd
@@ -156,12 +201,12 @@ def test_moe_expert_parallel_matches():
     x = jnp.asarray(np.random.default_rng(0).normal(size=(4, 16, cfg.d_model)), jnp.float32)
     y0, _ = m.apply_moe(params, x, cfg)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = shd.ShardingCtx(mesh)
-    with shd.activate(ctx), shd.mesh_ctx(mesh):
+    with shd.activate(ctx), jax.set_mesh(mesh):
         pspecs = shd.param_specs(params)
-        f = shd.sharded_jit(lambda p, xx: m.apply_moe(p, xx, cfg)[0],
-                            in_shardings=(pspecs, P(("data",), None, None)))
+        f = jax.jit(lambda p, xx: m.apply_moe(p, xx, cfg)[0],
+                    in_shardings=(pspecs, P(("data",), None, None)))
         y1 = f(params, x)
     np.testing.assert_allclose(np.asarray(y0), np.asarray(y1), rtol=3e-3, atol=3e-3)
     print("EP OK")

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis import given, settings
+from hypothesis import example, given, settings
 from hypothesis import strategies as st
 
 from repro.configs import paper_llama
@@ -146,6 +146,8 @@ def test_paged_decode_quantized_differential(group, window, chunk, seed):
     ragged=st.booleans(),
     seed=st.integers(min_value=0, max_value=10**6),
 )
+# clipped 8 of 2,304 values when the slot-0 row kept only 2x headroom
+@example(group=1, window=6, ragged=False, seed=873547)
 def test_varlen_quantized_differential(group, window, ragged, seed):
     """Packed varlen, quantized pool: same oracle chain as paged decode,
     over mixed prefill/decode raggedness (per-sequence kv_len, padding
@@ -203,7 +205,6 @@ def test_gather_pages_dequantizes():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-7)
 
 
-@pytest.mark.skipif("fp8" not in quant.available(), reason="host jax lacks fp8")
 def test_fp8_is_a_dtype_swap():
     """The fp8 spec rides the exact same plumbing — only (dtype, qmax)
     differ. One mirror-vs-dequantized-oracle pass is enough to pin it."""
