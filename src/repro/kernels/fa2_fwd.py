@@ -149,6 +149,7 @@ def fa2_fwd_pallas(
     ]
     call = pl.pallas_call(
         kernel,
+        name="fa2_fwd",
         grid=(b, hq, n_q, n_k),
         in_specs=in_specs,
         out_specs=out_specs,

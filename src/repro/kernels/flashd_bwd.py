@@ -159,6 +159,7 @@ def flashd_bwd_pallas(
     )
     dq = pl.pallas_call(
         dq_kernel,
+        name="flashd_bwd_dq",
         grid=(b, hq, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h, iq, ik: (b_, h, iq, 0)),
@@ -186,6 +187,7 @@ def flashd_bwd_pallas(
 
     dk, dv_out = pl.pallas_call(
         dkv_kernel,
+        name="flashd_bwd_dkv",
         grid=(b, hkv, n_k, n_q * g),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), qhead),

@@ -288,6 +288,7 @@ def flashd_decode_pallas(
             out_shape.append(jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32))
         call = pl.pallas_call(
             kernel,
+            name="flashd_decode_fused",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=grid,
@@ -315,6 +316,7 @@ def flashd_decode_pallas(
     )
     call = pl.pallas_call(
         kernel,
+        name="flashd_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -484,6 +486,7 @@ def flashd_decode_paged_pallas(
     )
     call = pl.pallas_call(
         kernel,
+        name="flashd_decode_paged",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, dv), q.dtype),
         interpret=interpret,

@@ -233,6 +233,7 @@ def flashd_fwd_pallas(
     ]
     call = pl.pallas_call(
         kernel,
+        name="flashd_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,

@@ -231,6 +231,7 @@ def flashd_varlen_pallas(
     )
     call = pl.pallas_call(
         kernel,
+        name="flashd_varlen",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hkv, g, t, dv), q.dtype),
         interpret=interpret,

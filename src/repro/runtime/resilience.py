@@ -36,8 +36,8 @@ tests/test_checkpoint_resilience.py):
 * `StragglerMonitor` — per-step wall-time EWMA + robust z-score; flags
   slow steps/pods and invokes a callback (in production: exclude the pod
   from the next allocation / re-mesh; here: a recorded decision, so the
-  policy is unit-testable without real stragglers). The serving engine
-  reuses it as a per-step watchdog (`Engine.stats()["slow_steps"]`).
+  policy is unit-testable without real stragglers). The training
+  launcher (`launch/train.py`) feeds it every step's wall time.
 
 * `ElasticPlan` — given a new device count, recompute the mesh shape and
   produce (mesh, shardings) so a checkpoint written at one scale restores
@@ -49,11 +49,13 @@ tests/test_checkpoint_resilience.py):
 from __future__ import annotations
 
 import dataclasses
+import shutil
 import time
 from typing import Callable, Dict, Iterable, List, Optional, Tuple, Type
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime import checkpoint as ckpt
 
@@ -286,16 +288,15 @@ def run_resilient(
                 history.append({"step": step, **{k: float(v) for k, v in metrics.items()}})
                 step += 1
                 if step % ckpt_every == 0 or step == total_steps:
-                    if on_save is not None:
-                        on_save(step, state)
-                    ckpt.save(ckpt_dir, step, state, extra={"data_step": step})
-                    if keep is not None:
-                        for s in ckpt.valid_steps(ckpt_dir)[:-keep]:
-                            import shutil as _sh
-
-                            _sh.rmtree(
-                                f"{ckpt_dir}/step_{s:08d}", ignore_errors=True
-                            )
+                    with TraceAnnotation("repro.train.ckpt", step=step):
+                        if on_save is not None:
+                            on_save(step, state)
+                        ckpt.save(ckpt_dir, step, state,
+                                  extra={"data_step": step})
+                        if keep is not None:
+                            for s in ckpt.valid_steps(ckpt_dir)[:-keep]:
+                                shutil.rmtree(f"{ckpt_dir}/step_{s:08d}",
+                                              ignore_errors=True)
             return state, history
         except policy.retryable as e:
             restarts += 1

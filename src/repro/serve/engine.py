@@ -97,10 +97,11 @@ from typing import Dict, List, Optional, Sequence, Set, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import ModelConfig, get_model
 from repro.models.transformer import forward_packed, packed_mixers_ok, prefill_lm
-from repro.runtime.resilience import FaultInjector, InjectedFault, StragglerMonitor
+from repro.runtime.resilience import FaultInjector, InjectedFault
 from repro.serve.scheduler import TERMINAL, Request, Scheduler, StepPlan
 
 __all__ = ["ServeConfig", "Engine", "sample_token"]
@@ -290,7 +291,7 @@ class Engine:
             "prefix_lookups": 0, "prefix_hits": 0, "hit_tokens": 0,
             "prompt_tokens": 0, "preemptions": 0,
             "failed": 0, "retried": 0, "expired": 0,
-            "downgrades": 0, "slow_steps": 0,
+            "downgrades": 0,
             "spec_rounds": 0, "spec_drafted": 0, "spec_accepted": 0,
         }
         # ---- speculative decoding (DESIGN.md §3.9) ----
@@ -331,8 +332,6 @@ class Engine:
         self._injector = fault_injector
         self._kernel_faults = 0  # consecutive kernel-site faults (downgrade)
         self._step_faults = 0  # consecutive faulted steps (victim charging)
-        self._step_no = 0  # engine-lifetime serve steps (watchdog key)
-        self._watchdog = StragglerMonitor(on_straggler=self._note_slow_step)
         self._sched: Optional[Scheduler] = None  # last/current serve's scheduler
         self._resume_state: Optional[dict] = None  # restored snapshot, pre-resume
 
@@ -390,13 +389,6 @@ class Engine:
         """Serve-loop device→host sync: the host_sync injection site."""
         self._inj("host_sync", rid)
         return self._to_host(x)
-
-    def _note_slow_step(self, step: int, dt: float, ewma: float) -> None:
-        self._stats["slow_steps"] += 1
-
-    def _bump_step(self) -> int:
-        self._step_no += 1
-        return self._step_no
 
     def _note_fault(self, exc: InjectedFault) -> None:
         """Record an injected fault; consecutive kernel-site faults on a
@@ -460,6 +452,7 @@ class Engine:
                     deadlines) -> Scheduler:
         if deadlines is None and self.sc.deadline_s > 0:
             deadlines = [self.sc.deadline_s] * len(requests)
+        self._call_stats = {"packed_rows": 0, "real_rows": 0, "steps": {}}
         sched = Scheduler(
             requests, max_new_tokens, self.sc.max_batch, self.sc.eos_id,
             priorities=priorities, deadlines=deadlines,
@@ -483,8 +476,13 @@ class Engine:
     def stats(self) -> dict:
         """Serving counters, cumulative over this engine's lifetime:
         prefix-cache hit rate (token-weighted), preemption / eviction /
-        donation counts, pool occupancy, and the last serve() call's
-        per-request TTFT."""
+        donation counts, pool occupancy. Of the last serve() call only:
+        per-request TTFT (`ttft`), status (`request_status`) and first
+        admission into a slot (`admitted_at`), all in scheduler seconds;
+        and, of the mixed loop, its steps by kind (`steps`: mixed /
+        chunk / spec) and the rows its packed mixed steps dispatched
+        (`packed_rows`, after padding and bucketing) of which
+        `real_rows` held a token."""
         s = dict(self._stats)
         s["hit_rate"] = s["hit_tokens"] / max(s["prompt_tokens"], 1)
         s["prefix_cache_enabled"] = self._cache_on
@@ -533,6 +531,10 @@ class Engine:
             s["fault_checks"] = dict(self._injector.calls)
         if self._sched is not None:
             s["request_status"] = dict(self._sched.status)
+            s["admitted_at"] = dict(self._sched.admitted_at)
+            s["packed_rows"] = self._call_stats["packed_rows"]
+            s["real_rows"] = self._call_stats["real_rows"]
+            s["steps"] = dict(self._call_stats["steps"])
         return s
 
     # ---- jitted device loops ----
@@ -823,7 +825,6 @@ class Engine:
                         break
                     refill()
                     continue
-                self._watchdog.start_step()
                 self._key, k = jax.random.split(self._key)
                 try:
                     self._inj("kernel_dispatch")
@@ -837,7 +838,6 @@ class Engine:
                     # because committed host state never advanced
                     self._on_step_fault(sched, e, sched.fault_slot)
                     continue
-                self._watchdog.end_step(self._bump_step())
                 self._clear_fault_streak()
                 cache, tok, pos = cache2, tok2, pos2
                 for s in sched.absorb_chunk(toks_np):
@@ -1358,7 +1358,6 @@ class Engine:
                 if self._spec is not None:
                     # speculative round replaces the per-token chunk loop:
                     # growth happens inside _plan_grown, per-slot
-                    self._watchdog.start_step()
                     try:
                         finished = self._spec_round(
                             sched, alloc, ctx, budget=spec_budget,
@@ -1372,7 +1371,6 @@ class Engine:
                             ),
                         )
                         continue
-                    self._watchdog.end_step(self._bump_step())
                     self._clear_fault_streak()
                     for s in finished:
                         self._pool_retire(sched, alloc, ctx, s)
@@ -1398,7 +1396,6 @@ class Engine:
                         self._pool_fault_slot(sched, alloc, ctx, s)
                 if not sched.has_active():
                     continue
-                self._watchdog.start_step()
                 self._key, k = jax.random.split(self._key)
                 try:
                     self._inj("kernel_dispatch")
@@ -1415,7 +1412,6 @@ class Engine:
                         lambda v: self._pool_fault_slot(sched, alloc, ctx, v),
                     )
                     continue
-                self._watchdog.end_step(self._bump_step())
                 self._clear_fault_streak()
                 ctx.cache, tok, pos = cache2, tok2, pos2
                 for s in sched.absorb_chunk(toks_np):
@@ -1450,7 +1446,9 @@ class Engine:
         the radix prefix cache applies here too: chunked prefill starts at
         the first UNCACHED token (`fed0`), so a warm shared prefix skips
         its chunks entirely."""
-        from repro.kernels.tuning import bucket_pow2, choose_varlen_blocks
+        from repro.kernels.tuning import (
+            bucket_pow2, choose_varlen_blocks, padded_rows,
+        )
         from repro.runtime.kvcache import PageError, pages_for
 
         lay = self._page_layout
@@ -1540,14 +1538,15 @@ class Engine:
                     alloc.insert(ctx.seq_of[s], sl.prompt)
                     ctx.inserted.add(s)
 
-        def dispatch(plan: StepPlan) -> np.ndarray:
-            """Pack the plan into flat block_q-aligned arrays (bucketed to
-            a power of two) and run the jitted mixed step."""
+        def pack(plan: StepPlan):
+            """Pack the plan into flat block_q-aligned device arrays
+            (bucketed to a power of two); also returns the packed row
+            count and how many of those rows hold a token."""
             off = 0
             spans = []
             for seg in plan.segments:
                 spans.append(off)
-                off += -(-len(seg.tokens) // block_q) * block_q
+                off += padded_rows(len(seg.tokens), block_q)
             total = bucket_pow2(max(off, 1), lo=block_q)
             tokens = np.zeros((total,), np.int32)
             seq_ids = np.full((total,), -1, np.int32)
@@ -1562,102 +1561,128 @@ class Engine:
                 kv_len[seg.slot] = seg.start + n
                 if seg.emits:
                     last_rows[seg.slot] = o + n - 1
-            self._key, k = jax.random.split(self._key)
-            self._inj("kernel_dispatch")
-            cache2, toks = self._mixed(
-                self.params, ctx.cache,
-                jnp.asarray(tokens), jnp.asarray(seq_ids),
-                jnp.asarray(positions), jnp.asarray(kv_len),
-                jnp.asarray(last_rows), k, block_q,
-            )
-            self._inj("device_step")
-            toks_np = self._sync(toks)  # one sync per mixed step
+            arrays = tuple(jnp.asarray(a) for a in
+                           (tokens, seq_ids, positions, kv_len, last_rows))
+            return arrays, total, plan.n_tokens
+
+        def mixed_step(step) -> List[int]:
+            """One packed varlen step: plan, pack, dispatch, sync, commit."""
+            with TraceAnnotation("repro.serve.plan"):
+                plan = self._plan_grown(sched, alloc, ctx, budget, pchunk)
+            if not plan.segments:
+                return []
+            with TraceAnnotation("repro.serve.pack"):
+                arrays, rows, real = pack(plan)
+            step.set_metadata(rows=rows, real_rows=real)
+            self._call_stats["packed_rows"] += rows
+            self._call_stats["real_rows"] += real
+            with TraceAnnotation("repro.serve.dispatch"):
+                self._key, k = jax.random.split(self._key)
+                self._inj("kernel_dispatch")
+                cache2, toks = self._mixed(self.params, ctx.cache, *arrays,
+                                           k, block_q)
+                self._inj("device_step")
+            with TraceAnnotation("repro.serve.sync"):
+                toks_np = self._sync(toks)  # one sync per mixed step
             # commit the device cache only past the sync: a step fault
             # above discards the step entirely, so its retry is exact
             ctx.cache = cache2
-            return toks_np
+            with TraceAnnotation("repro.serve.commit"):
+                return sched.commit(plan, toks_np)
 
-        def decode_chunk_phase():
+        def decode_chunk_phase(step) -> List[int]:
             """No prefill in flight: the sequential engines' jitted
             multi-token decode loop (one dispatch + one sync per
             `decode_chunk` tokens). Device tok/pos are rebuilt from the
             scheduler's host state, so packed steps and chunk phases
             interleave freely; dead slots carry zeroed table rows, so
-            their lockstep writes land on the garbage page. Returns None
-            when growth faults emptied the batch (nothing to step).
-            Growth faults are rid-scoped (only that slot rolls back);
-            dispatch/sync faults are step-wide and propagate to the
-            caller's retry handler."""
-            for s in range(b):
-                sl = sched.slots[s]
-                if not sl.live:
-                    continue
-                try:
-                    self._inj("page_alloc", sl.rid)
-                    self._pool_grow(sched, alloc, ctx, s,
-                                    min(sl.kv + chunk_n, self.sc.max_len))
-                except InjectedFault as e:
-                    self._note_fault(e)
-                    self._pool_fault_slot(sched, alloc, ctx, s)
+            their lockstep writes land on the garbage page. Growth faults
+            are rid-scoped (only that slot rolls back; a batch they empty
+            has nothing to step); dispatch/sync faults are step-wide and
+            propagate to the caller's retry handler."""
+            with TraceAnnotation("repro.serve.plan"):
+                for s in range(b):
+                    sl = sched.slots[s]
+                    if not sl.live:
+                        continue
+                    try:
+                        self._inj("page_alloc", sl.rid)
+                        self._pool_grow(sched, alloc, ctx, s,
+                                        min(sl.kv + chunk_n, self.sc.max_len))
+                    except InjectedFault as e:
+                        self._note_fault(e)
+                        self._pool_fault_slot(sched, alloc, ctx, s)
             if not sched.has_active():
-                return None
-            tok = jnp.asarray([sl.pending for sl in sched.slots], jnp.int32)
-            pos = jnp.asarray([sl.kv for sl in sched.slots], jnp.int32)
-            self._key, k = jax.random.split(self._key)
-            self._inj("kernel_dispatch")
-            cache2, _, _, toks = self._chunk(
-                self.params, ctx.cache, tok, pos, k, chunk_n
-            )
-            self._inj("device_step")
-            toks_np = self._sync(toks)  # one sync per chunk
-            ctx.cache = cache2  # commit past the sync (see dispatch)
-            return toks_np
+                return []
+            with TraceAnnotation("repro.serve.pack"):
+                tok = jnp.asarray([sl.pending for sl in sched.slots], jnp.int32)
+                pos = jnp.asarray([sl.kv for sl in sched.slots], jnp.int32)
+            # the chunk computes every slot, live or not, chunk_n times
+            step.set_metadata(rows=b * chunk_n,
+                              real_rows=sched.active_count() * chunk_n)
+            with TraceAnnotation("repro.serve.dispatch"):
+                self._key, k = jax.random.split(self._key)
+                self._inj("kernel_dispatch")
+                cache2, _, _, toks = self._chunk(
+                    self.params, ctx.cache, tok, pos, k, chunk_n
+                )
+                self._inj("device_step")
+            with TraceAnnotation("repro.serve.sync"):
+                toks_np = self._sync(toks)  # one sync per chunk
+            ctx.cache = cache2  # commit past the sync (see mixed_step)
+            with TraceAnnotation("repro.serve.commit"):
+                return sched.absorb_chunk(toks_np)
 
-        try:
-            try_admit()
-            self.peak_active = sched.note_peak()
-            while sched.has_active() or sched.queue:
-                for s in sched.expire_overdue():
-                    self._pool_retire(sched, alloc, ctx, s)
-                if not sched.has_active():
-                    if not self._await_backoff(sched):
-                        break
-                    try_admit()
-                    self.peak_active = sched.note_peak()
-                    continue
-                self._watchdog.start_step()
-                try:
-                    if self._spec is not None:
-                        # speculative rounds subsume both phases: verify
-                        # chains AND prefill chunks ride one packed step
-                        finished = self._spec_round(
-                            sched, alloc, ctx, budget=budget,
-                            pchunk=pchunk, block_q=block_q,
-                        )
-                    elif not any(sl.prefilling for sl in sched.slots):
-                        toks_np = decode_chunk_phase()
-                        finished = (sched.absorb_chunk(toks_np)
-                                    if toks_np is not None else [])
-                    else:
-                        plan = self._plan_grown(sched, alloc, ctx,
-                                                budget, pchunk)
-                        finished = (sched.commit(plan, dispatch(plan))
-                                    if plan.segments else [])
-                except InjectedFault as e:
-                    # discard the uncommitted step and retry it — exact,
-                    # because committed host state never advanced
-                    self._on_step_fault(
-                        sched, e,
-                        lambda v: self._pool_fault_slot(sched, alloc, ctx, v),
-                    )
-                    continue
-                self._watchdog.end_step(self._bump_step())
-                self._clear_fault_streak()
+        def admit(finished=()):
+            """Index finished prefills, retire finished slots, admit."""
+            with TraceAnnotation("repro.serve.admit"):
                 note_prefilled()
                 for s in finished:
                     self._pool_retire(sched, alloc, ctx, s)
                 try_admit()
                 self.peak_active = sched.note_peak()
+
+        try:
+            admit()
+            while sched.has_active() or sched.queue:
+                for s in sched.expire_overdue():
+                    self._pool_retire(sched, alloc, ctx, s)
+                if not sched.has_active():
+                    with TraceAnnotation("repro.serve.idle"):
+                        more = self._await_backoff(sched)
+                    if not more:
+                        break
+                    admit()
+                    continue
+                # speculative rounds subsume both phases: verify chains
+                # AND prefill chunks ride one packed step
+                kind = ("spec" if self._spec is not None else
+                        "mixed" if any(sl.prefilling for sl in sched.slots)
+                        else "chunk")
+                steps = self._call_stats["steps"]
+                steps[kind] = steps.get(kind, 0) + 1
+                with TraceAnnotation("repro.serve.step", kind=kind,
+                                     live=sched.active_count()) as step:
+                    try:
+                        if kind == "spec":
+                            finished = self._spec_round(
+                                sched, alloc, ctx, budget=budget,
+                                pchunk=pchunk, block_q=block_q,
+                            )
+                        elif kind == "chunk":
+                            finished = decode_chunk_phase(step)
+                        else:
+                            finished = mixed_step(step)
+                    except InjectedFault as e:
+                        # discard the uncommitted step and retry it — exact,
+                        # because committed host state never advanced
+                        self._on_step_fault(
+                            sched, e,
+                            lambda v: self._pool_fault_slot(sched, alloc, ctx, v),
+                        )
+                        continue
+                    self._clear_fault_streak()
+                    admit(finished)
         except Exception:
             self._recover_paged(sched, alloc, ctx)
             raise

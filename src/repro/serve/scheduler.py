@@ -231,6 +231,9 @@ class Scheduler:
         # resume; the serving-latency signal BENCH_serve.json /
         # BENCH_prefix.json track
         self.first_token_at: Dict[int, float] = {}
+        # scheduler seconds of each request's first admission into a
+        # slot; a retry or preemption re-admission keeps the first
+        self.admitted_at: Dict[int, float] = {}
         self._t0 = time.monotonic()
 
     def now(self) -> float:
@@ -413,6 +416,7 @@ class Scheduler:
         sl.retries = req.retries
         sl.admit_seq = self._admit_counter
         self._admit_counter += 1
+        self.admitted_at.setdefault(sl.rid, self.now())
         sl.fed = sl.kv = len(sl.prompt)
         sl.pending = first_token
         self.status[req.rid] = RUNNING
@@ -440,6 +444,7 @@ class Scheduler:
         sl.retries = req.retries
         sl.admit_seq = self._admit_counter
         self._admit_counter += 1
+        self.admitted_at.setdefault(sl.rid, self.now())
         sl.fed = sl.kv = fed0
         sl.pending = 0
         self.status[req.rid] = RUNNING

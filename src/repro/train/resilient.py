@@ -39,6 +39,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime.resilience import (
     DivergenceRollback,
@@ -123,27 +124,33 @@ def train_resilient(
         raise DivergenceRollback(step, loss, ref)
 
     def supervised_step(state, data_step: int):
-        if injector is not None:
-            injector.check("data_batch")
-        batch = jax.tree.map(jnp.asarray, data.batch(data_step))
-        if chaos_hook is not None:
-            corrupted = chaos_hook(data_step, state)
-            if corrupted is not None:
-                state = corrupted
-        if injector is not None:
-            injector.check("grad_step")
-            injector.check("optimizer_update")
-        new_state, metrics = step_fn(state, batch)
-        if injector is not None:
-            injector.check("collective")  # a lost reduction loses the step
-        loss = float(metrics["loss"])  # host sync: the commit point
-        _spike_check(data_step, loss)
-        losses[data_step] = loss
-        if on_step is not None:
-            if injector is not None:
-                counters["faults"] = injector.total_fired
-            on_step(data_step, {k: float(v) for k, v in metrics.items()}, counters)
-        return new_state, metrics
+        with TraceAnnotation("repro.train.step", step=data_step):
+            with TraceAnnotation("repro.train.batch"):
+                if injector is not None:
+                    injector.check("data_batch")
+                batch = jax.tree.map(jnp.asarray, data.batch(data_step))
+            if chaos_hook is not None:
+                corrupted = chaos_hook(data_step, state)
+                if corrupted is not None:
+                    state = corrupted
+            with TraceAnnotation("repro.train.dispatch"):
+                if injector is not None:
+                    injector.check("grad_step")
+                    injector.check("optimizer_update")
+                new_state, metrics = step_fn(state, batch)
+                if injector is not None:
+                    injector.check("collective")  # a lost reduction loses the step
+            with TraceAnnotation("repro.train.sync"):
+                loss = float(metrics["loss"])  # host sync: the commit point
+            with TraceAnnotation("repro.train.commit"):
+                _spike_check(data_step, loss)
+                losses[data_step] = loss
+                if on_step is not None:
+                    if injector is not None:
+                        counters["faults"] = injector.total_fired
+                    on_step(data_step, {k: float(v) for k, v in metrics.items()},
+                            counters)
+            return new_state, metrics
 
     def on_save(step: int, state) -> None:
         if injector is not None:
