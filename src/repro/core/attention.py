@@ -330,6 +330,7 @@ def varlen_attention(
     block_q: Optional[int] = None,
     k_scale: Optional[jax.Array] = None,  # [P, Hkv] f32 — quantized pool
     v_scale: Optional[jax.Array] = None,  # [P, Hkv] f32
+    layer: Optional[jax.Array] = None,  # pool stacked [L, P, ...]: read this layer
 ) -> jax.Array:
     """Packed varlen attention over a paged KV cache → o [T, Hq, dv].
 
@@ -352,9 +353,13 @@ def varlen_attention(
     (DESIGN.md §3.8): the kernel dequantizes tiles in VMEM after the DMA
     gather; this mirror dequantizes during its page gather — identical
     arithmetic, so it stays the differential oracle.
+
+    `layer` marks the model's stacked pool (pages [L, P, page, Hkv, ·],
+    scales [L, P, Hkv]): the kernel reads that layer through its index
+    maps, the mirror gathers from it (DESIGN.md §3.4).
     """
     t, hq, d = q.shape
-    _, page, hkv, dv = v_pages.shape
+    page, hkv, dv = v_pages.shape[-3:]
     g = hq // hkv
     if scale is None:
         scale = float(1.0 / (d ** 0.5))
@@ -383,14 +388,14 @@ def varlen_attention(
         o = kernel_ops.pallas_varlen(
             q, k_pages, v_pages, block_tbl, seq_ids, q_pos, kv_len,
             scale=scale, window=window, chunk=chunk, block_q=block_q,
-            k_scale=k_scale, v_scale=v_scale,
+            k_scale=k_scale, v_scale=v_scale, layer=layer,
         )
         return o[:t]
 
     # jnp mirror: gather each row's sequence cache, one einsum per pack.
     sid = jnp.maximum(seq_ids, 0)
-    k_cache = gather_pages(k_pages, block_tbl, scales=k_scale)  # [B, S, Hkv, d]
-    v_cache = gather_pages(v_pages, block_tbl, scales=v_scale)
+    k_cache = gather_pages(k_pages, block_tbl, scales=k_scale, layer=layer)
+    v_cache = gather_pages(v_pages, block_tbl, scales=v_scale, layer=layer)
     s_tot = k_cache.shape[1]
     kt = k_cache[sid].astype(jnp.float32)  # [T, S, Hkv, d]
     vt = v_cache[sid].astype(jnp.float32)
@@ -420,6 +425,7 @@ def gather_pages(
     pages: jax.Array,
     block_tbl: jax.Array,
     scales: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,
 ) -> jax.Array:
     """[P, page, Hkv, ·] pool + [B, N] table → contiguous [B, N·page, Hkv, ·].
 
@@ -430,9 +436,15 @@ def gather_pages(
 
     With `scales` ([P, Hkv] f32, a quantized pool's per-(page, head)
     side-band) the gathered view is dequantized to f32 — the mirror of the
-    kernels' in-tile dequant (DESIGN.md §3.8)."""
+    kernels' in-tile dequant (DESIGN.md §3.8).
+
+    With `layer` the pool is stacked ([L, P, page, Hkv, ·], scales
+    [L, P, Hkv]) and the gather reads that layer's pages straight out of
+    the stack."""
     b, n = block_tbl.shape
-    _, page, hkv = pages.shape[:3]
+    page, hkv = pages.shape[-3:-1]
+    if layer is not None:
+        pages, scales = pages[layer], None if scales is None else scales[layer]
     out = pages[block_tbl]  # [B, N, page, Hkv, ·]
     if scales is not None:
         out = out.astype(jnp.float32) * scales[block_tbl][:, :, None, :, None]
@@ -452,6 +464,7 @@ def decode_attention_paged(
     n_splits: Optional[int] = None,
     k_scale: Optional[jax.Array] = None,  # [P, Hkv] f32 — quantized pool
     v_scale: Optional[jax.Array] = None,
+    layer: Optional[jax.Array] = None,  # pool stacked [L, P, ...]: read this layer
 ) -> jax.Array:
     """Single-step decode against a paged KV cache (DESIGN.md §3.4).
 
@@ -467,8 +480,8 @@ def decode_attention_paged(
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
-    k_cache = gather_pages(k_pages, block_tbl, scales=k_scale)
-    v_cache = gather_pages(v_pages, block_tbl, scales=v_scale)
+    k_cache = gather_pages(k_pages, block_tbl, scales=k_scale, layer=layer)
+    v_cache = gather_pages(v_pages, block_tbl, scales=v_scale, layer=layer)
     return decode_attention(
         q, k_cache, v_cache, cache_len, scale=scale, window=window,
         chunk=chunk, n_splits=n_splits,

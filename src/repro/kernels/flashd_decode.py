@@ -19,9 +19,10 @@ fused=True (default) — the split axis is the innermost sequential
 
 paged (`flashd_decode_paged_pallas`) — the fused carry structure, but K/V
   live in a global page pool ([P, page, Hkv, d]) addressed through a
-  per-sequence block table. The table (and cache_len) enter as
-  scalar-prefetch operands: the K/V BlockSpec index maps read
-  `tbl[b, ip]` so each sequential grid step DMAs the *physical* page of
+  per-sequence block table. The table, cache_len and the layer of a
+  stacked [L, P, ...] pool enter as scalar-prefetch operands: the K/V
+  BlockSpec index maps read `(layer, tbl[b, ip])` so each sequential grid
+  step DMAs the *physical* page of
   logical page ip — the gather happens in the DMA engine, the kernel body
   and the in-VMEM merge are identical to the fused path. This is what the
   paged serving cache (runtime/kvcache.py, DESIGN.md §3.4) decodes with.
@@ -352,7 +353,7 @@ def flashd_decode_pallas(
 # ---------------------------------------------------------------------------
 
 def _decode_paged_kernel(
-    tbl_ref, cache_len_ref,  # scalar prefetch (SMEM)
+    tbl_ref, cache_len_ref, layer_ref,  # scalar prefetch (SMEM); layer: index maps only
     q_ref, k_ref, v_ref,  # VMEM blocks (k/v: the ip-th *physical* page)
     *refs,  # quantized: (ks, vs) scale blocks; then o, then VMEM carry
     page: int,
@@ -396,26 +397,38 @@ def _decode_paged_kernel(
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
-def page_scale_blocks(k_scale, v_scale):
-    """Kernel views of a quantized pool's per-(page, head) scales.
+def stacked_pool(k_pages, v_pages, k_scale, v_scale, layer):
+    """Kernel views of a page pool → (layer [1] i32, k, v, scale blocks).
 
-    A grid step fetches one whole page, all kv heads: the [P, page, Hkv, d]
-    pool is read unreshaped as (1, page, Hkv, d) blocks, whose trailing
-    dims equal the array's for every head width (a one-head (page, d)
-    block tiles only when d is a multiple of 128, and a reshape of the pool
-    would copy it on every call). Scales [P, Hkv] become [P, 1, Hkv] so the
-    page's (1, Hkv) row is such a block too. () for a native pool."""
-    if k_scale is None:
-        return ()
-    return tuple(
-        jnp.asarray(sc, jnp.float32).reshape(sc.shape[0], 1, sc.shape[1])
-        for sc in (k_scale, v_scale)
+    The paged kernels read one layer of a STACKED pool — pages
+    [L, P, page, Hkv, d], scales [L, P, Hkv] — whose layer rides in as a
+    scalar-prefetch operand of the K/V index maps. The model's layer loop
+    therefore hands every layer the whole pool and never copies one layer
+    out and back (DESIGN.md §3.4). layer=None takes a single-layer
+    [P, page, Hkv, d] pool as a stack of one (a leading-axis reshape).
+
+    A grid step fetches one whole page, all kv heads: the pool is read
+    unreshaped as (1, page, Hkv, d) blocks, whose trailing dims equal the
+    array's for every head width (a one-head (page, d) block tiles only
+    when d is a multiple of 128, and a reshape of the pool would copy it
+    on every call). The layer's scales [P, Hkv] become [P, 1, Hkv] so the
+    page's (1, Hkv) row is such a block too; that reshape changes the
+    tiling and so copies, which is why it takes the one layer's scales
+    (P · Hkv floats) and not the stack's. () for a native pool."""
+    if layer is None:
+        layer = 0
+        k_pages, v_pages = k_pages[None], v_pages[None]
+    elif k_scale is not None:
+        k_scale, v_scale = k_scale[layer], v_scale[layer]
+    scales = () if k_scale is None else tuple(
+        jnp.asarray(sc, jnp.float32)[:, None, :] for sc in (k_scale, v_scale)
     )
+    return jnp.asarray(layer, jnp.int32).reshape(1), k_pages, v_pages, scales
 
 
 def flashd_decode_paged_pallas(
     q: jax.Array,  # [B, Hq, d] — one token per sequence
-    k_pages: jax.Array,  # [P, page, Hkv, d] — global page pool
+    k_pages: jax.Array,  # [P, page, Hkv, d] — global page pool ([L, P, ...]: layer)
     v_pages: jax.Array,  # [P, page, Hkv, dv]
     block_tbl: jax.Array,  # [B, N] i32 — physical page id of logical page j
     cache_len: jax.Array,  # [B] i32
@@ -425,6 +438,7 @@ def flashd_decode_paged_pallas(
     chunk: int = 0,
     k_scale: Optional[jax.Array] = None,  # [P, Hkv] f32 — quantized pool
     v_scale: Optional[jax.Array] = None,  # [P, Hkv] f32
+    layer: Optional[jax.Array] = None,  # i32: pool stacked [L, P, ...], read layer
     interpret: bool = False,
 ):
     """Fused FLASH-D decode over a paged KV cache → o [B, Hq, dv].
@@ -443,9 +457,15 @@ def flashd_decode_paged_pallas(
     DESIGN.md §3.8): the same index maps fetch the page's per-head f32
     scales and each head's tile is dequantized right after its upcast,
     before the scores — nothing downstream of the multiply changes.
+
+    With `layer` the pool (pages and scales) carries a leading layer axis
+    and the index maps read layer `layer` of it (`stacked_pool`).
     """
+    layer, k_pages, v_pages, scales = stacked_pool(
+        k_pages, v_pages, k_scale, v_scale, layer
+    )
     b, hq, d = q.shape
-    _, page, hkv, dv = v_pages.shape
+    _, _, page, hkv, dv = v_pages.shape
     n_tbl = block_tbl.shape[1]
     g = hq // hkv
     if scale is None:
@@ -455,29 +475,31 @@ def flashd_decode_paged_pallas(
     quantized = k_scale is not None
     block_tbl = jnp.asarray(block_tbl, jnp.int32)
     cache_len = jnp.asarray(cache_len, jnp.int32).reshape(b)
-    scales = page_scale_blocks(k_scale, v_scale)
 
     qg = q.reshape(b, hkv, g, d)
     kernel = functools.partial(
         _decode_paged_kernel, page=page, n_tbl=n_tbl, window=window,
         chunk=chunk, scale=scale, quantized=quantized,
     )
-    in_specs = [
-        pl.BlockSpec((1, hkv, g, d), lambda b_, ip, tbl, cl: (b_, 0, 0, 0)),
+    def page_of(b_, ip, tbl, cl, ly):
         # the physical page: logical page ip of row b_ through the table
-        pl.BlockSpec((1, page, hkv, d), lambda b_, ip, tbl, cl: (tbl[b_, ip], 0, 0, 0)),
-        pl.BlockSpec((1, page, hkv, dv), lambda b_, ip, tbl, cl: (tbl[b_, ip], 0, 0, 0)),
+        return ly[0], tbl[b_, ip]
+
+    in_specs = [
+        pl.BlockSpec((1, hkv, g, d), lambda b_, *_: (b_, 0, 0, 0)),
+        pl.BlockSpec((None, 1, page, hkv, d), lambda *a: (*page_of(*a), 0, 0, 0)),
+        pl.BlockSpec((None, 1, page, hkv, dv), lambda *a: (*page_of(*a), 0, 0, 0)),
     ]
     if quantized:  # per-(page, head) scales ride the same table indirection
         in_specs += [
-            pl.BlockSpec((1, 1, hkv), lambda b_, ip, tbl, cl: (tbl[b_, ip], 0, 0))
+            pl.BlockSpec((1, 1, hkv), lambda *a: (page_of(*a)[1], 0, 0))
         ] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, n_tbl),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
-            (1, hkv, g, dv), lambda b_, ip, tbl, cl: (b_, 0, 0, 0)
+            (1, hkv, g, dv), lambda b_, *_: (b_, 0, 0, 0)
         ),
         scratch_shapes=[
             pltpu.VMEM((hkv, g, dv), jnp.float32),
@@ -494,5 +516,5 @@ def flashd_decode_paged_pallas(
             dimension_semantics=("parallel", "arbitrary")
         ),
     )
-    o = call(block_tbl, cache_len, qg, k_pages, v_pages, *scales)
+    o = call(block_tbl, cache_len, layer, qg, k_pages, v_pages, *scales)
     return o.reshape(b, hq, dv)

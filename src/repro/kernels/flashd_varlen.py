@@ -57,7 +57,7 @@ from repro.core.blockwise import NEG_INF
 from repro.kernels.flashd_decode import (
     _merge_into_carry,
     _normalized_partial,
-    page_scale_blocks,
+    stacked_pool,
 )
 
 __all__ = ["flashd_varlen_pallas"]
@@ -84,6 +84,7 @@ def _varlen_partial(q, k, q_pos, kv_len, lo, *, page, window, chunk, scale, v):
 
 def _varlen_kernel(
     blk_seq_ref, blk_qmax_ref, blk_qmin_ref, kv_len_ref, tbl_ref,  # SMEM
+    layer_ref,  # SMEM, read only by the K/V index maps
     q_ref, qpos_ref, k_ref, v_ref,  # VMEM (k/v: the gathered physical page)
     *refs,  # quantized: (ks, vs) scale blocks; then o, then VMEM carry
     block_q: int,
@@ -149,7 +150,7 @@ def _varlen_kernel(
 
 def flashd_varlen_pallas(
     q: jax.Array,  # [T, Hq, d] — packed, block_q-aligned segments
-    k_pages: jax.Array,  # [P, page, Hkv, d] — global page pool
+    k_pages: jax.Array,  # [P, page, Hkv, d] — global page pool ([L, P, ...]: layer)
     v_pages: jax.Array,  # [P, page, Hkv, dv]
     block_tbl: jax.Array,  # [B, N] i32
     seq_ids: jax.Array,  # [T] i32 (−1 = padding row)
@@ -162,6 +163,7 @@ def flashd_varlen_pallas(
     block_q: int,
     k_scale: Optional[jax.Array] = None,  # [P, Hkv] f32 — quantized pool
     v_scale: Optional[jax.Array] = None,  # [P, Hkv] f32
+    layer: Optional[jax.Array] = None,  # i32: pool stacked [L, P, ...], read layer
     interpret: bool = False,
 ) -> jax.Array:
     """Packed varlen FLASH-D forward over a paged cache → o [T, Hq, dv].
@@ -174,9 +176,15 @@ def flashd_varlen_pallas(
     DESIGN.md §3.8): each per-(page, head) f32 scale rides the same
     block-table indirection as its page and the tile is dequantized right
     after its upcast, before the scores — the merge is untouched.
+
+    With `layer` the pool (pages and scales) carries a leading layer axis
+    and the index maps read layer `layer` of it (`stacked_pool`).
     """
+    layer, k_pages, v_pages, scales = stacked_pool(
+        k_pages, v_pages, k_scale, v_scale, layer
+    )
     t, hq, d = q.shape
-    _, page, hkv, dv = v_pages.shape
+    _, _, page, hkv, dv = v_pages.shape
     n_tbl = block_tbl.shape[1]
     g = hq // hkv
     if scale is None:
@@ -193,7 +201,6 @@ def flashd_varlen_pallas(
     kv_len = jnp.asarray(kv_len, jnp.int32).reshape(-1)
     blk_seq = seq_ids[::block_q]  # exact under the alignment contract
     blk_pos = q_pos.reshape(nb, block_q)
-    scales = page_scale_blocks(k_scale, v_scale)
 
     qg = q.reshape(t, hkv, g, d).transpose(1, 2, 0, 3)  # [Hkv, G, T, d]
 
@@ -202,23 +209,23 @@ def flashd_varlen_pallas(
         window=window, chunk=chunk, scale=scale, quantized=quantized,
     )
 
-    def page_of(ib, ip, bs, qmax, qmin, kl, tbl):
+    def page_of(ib, ip, bs, qmax, qmin, kl, tbl, ly):
         # the physical page: logical page ip of the block's sequence,
         # resolved through the table in the DMA descriptor
-        return tbl[jnp.maximum(bs[ib], 0), ip]
+        return ly[0], tbl[jnp.maximum(bs[ib], 0), ip]
 
     in_specs = [
         pl.BlockSpec((hkv, g, block_q, d), lambda ib, *_: (0, 0, ib, 0)),
         pl.BlockSpec((block_q, 1), lambda ib, *_: (ib, 0)),
-        pl.BlockSpec((1, page, hkv, d), lambda *a: (page_of(*a), 0, 0, 0)),
-        pl.BlockSpec((1, page, hkv, dv), lambda *a: (page_of(*a), 0, 0, 0)),
+        pl.BlockSpec((None, 1, page, hkv, d), lambda *a: (*page_of(*a), 0, 0, 0)),
+        pl.BlockSpec((None, 1, page, hkv, dv), lambda *a: (*page_of(*a), 0, 0, 0)),
     ]
     if quantized:  # per-(page, head) scales ride the same table indirection
         in_specs += [
-            pl.BlockSpec((1, 1, hkv), lambda *a: (page_of(*a), 0, 0))
+            pl.BlockSpec((1, 1, hkv), lambda *a: (page_of(*a)[1], 0, 0))
         ] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(nb, n_tbl),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(
@@ -241,7 +248,7 @@ def flashd_varlen_pallas(
     )
     o = call(
         blk_seq, blk_pos.max(axis=1), blk_pos.min(axis=1), kv_len,
-        jnp.asarray(block_tbl, jnp.int32),
+        jnp.asarray(block_tbl, jnp.int32), layer,
         qg, q_pos.reshape(t, 1), k_pages, v_pages, *scales,
     )
     return o.transpose(2, 0, 1, 3).reshape(t, hq, dv)

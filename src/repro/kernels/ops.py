@@ -220,13 +220,16 @@ def pallas_decode_paged(
     chunk: int = 0,
     k_scale: jax.Array | None = None,  # [P, Hkv] f32 — quantized pool
     v_scale: jax.Array | None = None,
+    layer: jax.Array | None = None,  # pool stacked [L, P, ...]: read this layer
 ):
     """Paged fused decode — the block table rides in as a scalar-prefetch
     operand, so K/V pages are gathered by the DMA engine (DESIGN.md §3.4).
     Page arrays are stored page-major ([P, page, Hkv, d]), which is already
     the kernel layout — no transpose on the hot path. When the pool is
     quantized (DESIGN.md §3.8) the per-(page, head) scales ride the same
-    indirection and tiles are dequantized in-kernel."""
+    indirection and tiles are dequantized in-kernel. With `layer` the pool
+    is the model's stacked [L, P, ...] cache leaf and the kernel's index
+    maps read that layer of it: no per-layer copy of the pool."""
     o = flashd_decode_paged_pallas(
         q[:, 0] if q.ndim == 4 else q,
         k_pages,
@@ -238,6 +241,7 @@ def pallas_decode_paged(
         chunk=chunk,
         k_scale=k_scale,
         v_scale=v_scale,
+        layer=layer,
         interpret=interpret_mode(),
     )
     return o[:, None]  # [B, 1, Hq, dv]
@@ -259,6 +263,7 @@ def pallas_varlen(
     block_q: int,
     k_scale: jax.Array | None = None,  # [P, Hkv] f32 — quantized pool
     v_scale: jax.Array | None = None,
+    layer: jax.Array | None = None,  # pool stacked [L, P, ...]: read this layer
 ):
     """Unified packed varlen step (DESIGN.md §3.5): prefill chunks and
     decode rows in ONE kernel dispatch, K/V gathered through the block
@@ -271,7 +276,7 @@ def pallas_varlen(
         jnp.asarray(q_pos, jnp.int32),
         jnp.asarray(kv_len, jnp.int32).reshape(-1),
         scale=scale, window=window, chunk=chunk, block_q=block_q,
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=k_scale, v_scale=v_scale, layer=layer,
         interpret=interpret_mode(),
     )
 
@@ -383,6 +388,7 @@ def jnp_decode_paged(
     chunk: int = 0,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
+    layer: jax.Array | None = None,
 ):
     from repro.core.attention import decode_attention_paged  # lazy: avoid cycle
 
@@ -392,7 +398,7 @@ def jnp_decode_paged(
         jnp.asarray(block_tbl, jnp.int32),
         jnp.asarray(cache_len, jnp.int32).reshape(-1),
         scale=scale, window=window, chunk=chunk,
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=k_scale, v_scale=v_scale, layer=layer,
     )
 
 
@@ -412,6 +418,7 @@ def jnp_varlen(
     block_q: int,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
+    layer: jax.Array | None = None,
 ):
     from repro.core.attention import varlen_attention  # lazy: avoid cycle
 
@@ -422,5 +429,5 @@ def jnp_varlen(
         jnp.asarray(q_pos, jnp.int32),
         jnp.asarray(kv_len, jnp.int32).reshape(-1),
         scale=scale, window=window, chunk=chunk, impl="flashd",
-        block_q=block_q, k_scale=k_scale, v_scale=v_scale,
+        block_q=block_q, k_scale=k_scale, v_scale=v_scale, layer=layer,
     )

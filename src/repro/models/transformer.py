@@ -469,8 +469,9 @@ def init_decode_cache(
     return cache
 
 
-def _decode_attn(p, x, cfg: ModelConfig, kind: str, cache, pos):
-    """One-token attention against the cache. pos: [B] absolute position."""
+def _decode_attn(p, x, cfg: ModelConfig, kind: str, cache, pos, layer):
+    """One-token attention against the cache. pos: [B] absolute position;
+    layer: this layer's index into a paged cache's stacked pool."""
     b = x.shape[0]
     hd = cfg.head_dim_
     cdt = cfg.compute_dtype
@@ -490,8 +491,7 @@ def _decode_attn(p, x, cfg: ModelConfig, kind: str, cache, pos):
         k = apply_rope(k, pos[:, None], cfg.rope_theta)
 
     if "tbl" in cache:  # paged layout (DESIGN.md §3.4) — global attn only
-        y, new_cache = _paged_attn_step(p, q, k, v, cfg, cache, pos)
-        return y, new_cache
+        return _paged_attn_step(p, q, k, v, cfg, cache, pos, layer)
 
     max_len = cache["k"].shape[1]
     write_idx = pos % max_len  # ring buffer (exact for local/chunked windows)
@@ -532,13 +532,17 @@ def _decode_attn(p, x, cfg: ModelConfig, kind: str, cache, pos):
     return y, {"k": k_cache, "v": v_cache}
 
 
-def _paged_attn_step(p, q, k, v, cfg: ModelConfig, cache, pos):
+def _paged_attn_step(p, q, k, v, cfg: ModelConfig, cache, pos, layer):
     """One-token attention against a paged cache: scatter the new K/V into
     the position's physical page via the block table, then attend through
     the table. Writes past the table (dead slots whose `pos` keeps
     advancing in the lockstep batch, or rows the engine retired by zeroing
     their table row) land on the garbage page 0 — the engine's convention
     for harmless speculative writes (DESIGN.md §3.4).
+
+    The pool leaves arrive stacked over layers ([L, P, page, Hkv, d],
+    scales [L, P, Hkv]); `layer` picks this layer's pages for the writes
+    and the attention, so the stack is updated in place (`_POOL_LEAVES`).
 
     Quantized pools (`k_scale`/`v_scale` leaves, DESIGN.md §3.8) quantize
     at write time: a slot-0 write fixes its page's per-head scale from
@@ -548,7 +552,7 @@ def _paged_attn_step(p, q, k, v, cfg: ModelConfig, cache, pos):
     b = q.shape[0]
     k_pages, v_pages, tbl = cache["k_pages"], cache["v_pages"], cache["tbl"]
     k_scale, v_scale = cache.get("k_scale"), cache.get("v_scale")
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     n_tbl = tbl.shape[1]
     bidx = jnp.arange(b)
     page_idx = pos // page
@@ -565,16 +569,16 @@ def _paged_attn_step(p, q, k, v, cfg: ModelConfig, cache, pos):
         # a row sharing its page with a slot-0 writer can't scatter a stale
         # scale over the fresh one
         spid = jnp.where(is_slot0, pid, 0)
-        k_scale = k_scale.at[spid].set(
-            jnp.where(is_slot0[:, None], quant.slot0_scale(k_new, spec), k_scale[0])
-        )
-        v_scale = v_scale.at[spid].set(
-            jnp.where(is_slot0[:, None], quant.slot0_scale(v_new, spec), v_scale[0])
-        )
-        k_new = quant.quantize_rows(k_new, k_scale[pid], spec)
-        v_new = quant.quantize_rows(v_new, v_scale[pid], spec)
-    k_pages = k_pages.at[pid, slot].set(k_new)
-    v_pages = v_pages.at[pid, slot].set(v_new)
+        k_scale = k_scale.at[layer, spid].set(jnp.where(
+            is_slot0[:, None], quant.slot0_scale(k_new, spec), k_scale[layer, 0]
+        ))
+        v_scale = v_scale.at[layer, spid].set(jnp.where(
+            is_slot0[:, None], quant.slot0_scale(v_new, spec), v_scale[layer, 0]
+        ))
+        k_new = quant.quantize_rows(k_new, k_scale[layer, pid], spec)
+        v_new = quant.quantize_rows(v_new, v_scale[layer, pid], spec)
+    k_pages = k_pages.at[layer, pid, slot].set(k_new)
+    v_pages = v_pages.at[layer, pid, slot].set(v_new)
     eff_len = pos + 1
 
     use_kernel = cfg.attn_impl.endswith("_pallas")
@@ -590,8 +594,8 @@ def _paged_attn_step(p, q, k, v, cfg: ModelConfig, cache, pos):
         # (returns None at trace time) when the rule doesn't seq-shard.
         o = maybe_cp_decode(
             q,
-            gather_pages(k_pages, tbl, scales=k_scale),
-            gather_pages(v_pages, tbl, scales=v_scale),
+            gather_pages(k_pages, tbl, scales=k_scale, layer=layer),
+            gather_pages(v_pages, tbl, scales=v_scale, layer=layer),
             eff_len, use_kernel=use_kernel,
         )
     if o is None:
@@ -600,12 +604,12 @@ def _paged_attn_step(p, q, k, v, cfg: ModelConfig, cache, pos):
 
             o = kernel_ops.pallas_decode_paged(
                 q, k_pages, v_pages, tbl, eff_len,
-                k_scale=k_scale, v_scale=v_scale,
+                k_scale=k_scale, v_scale=v_scale, layer=layer,
             )
         else:
             o = decode_attention_paged(
                 q, k_pages, v_pages, tbl, eff_len,
-                k_scale=k_scale, v_scale=v_scale,
+                k_scale=k_scale, v_scale=v_scale, layer=layer,
             )
     o = o.reshape(b, 1, cfg.n_heads * cfg.head_dim_)
     y = jnp.einsum("bsh,hd->bsd", o, p["wo"].astype(cfg.compute_dtype))
@@ -632,13 +636,15 @@ def packed_mixers_ok(cfg: ModelConfig) -> bool:
 
 
 def _packed_attn(p, x, cfg: ModelConfig, kind: str, cache, positions, seq_ids,
-                 kv_len, block_q):
+                 kv_len, block_q, layer):
     """Packed varlen attention for one layer: scatter the pack's new K/V
     into each row's physical page through the block table, then attend the
     pack through `varlen_attention` (the fused Pallas kernel under
     `*_pallas` impls, the jnp mirror otherwise). x [1, T, D]; positions /
     seq_ids [T] (−1 = padding row → writes land on the garbage page and
-    the row returns zeros); kv_len [B] per-sequence visible KV length."""
+    the row returns zeros); kv_len [B] per-sequence visible KV length.
+    The pool leaves are stacked over layers and `layer` picks this one's
+    pages, as in `_paged_attn_step`."""
     t = x.shape[1]
     hd = cfg.head_dim_
     cdt = cfg.compute_dtype
@@ -659,7 +665,7 @@ def _packed_attn(p, x, cfg: ModelConfig, kind: str, cache, positions, seq_ids,
 
     k_pages, v_pages, tbl = cache["k_pages"], cache["v_pages"], cache["tbl"]
     k_scale, v_scale = cache.get("k_scale"), cache.get("v_scale")
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     n_tbl = tbl.shape[1]
     sid = jnp.maximum(seq_ids, 0)
     page_idx = positions // page
@@ -677,21 +683,21 @@ def _packed_attn(p, x, cfg: ModelConfig, kind: str, cache, positions, seq_ids,
         # a pack writing slot 0 and slots 1..n of one page in the same
         # dispatch sees exactly the sequential write order's values
         spid = jnp.where(is_slot0, pid, 0)
-        k_scale = k_scale.at[spid].set(
-            jnp.where(is_slot0[:, None], quant.slot0_scale(k_new, spec), k_scale[0])
-        )
-        v_scale = v_scale.at[spid].set(
-            jnp.where(is_slot0[:, None], quant.slot0_scale(v_new, spec), v_scale[0])
-        )
-        k_new = quant.quantize_rows(k_new, k_scale[pid], spec)
-        v_new = quant.quantize_rows(v_new, v_scale[pid], spec)
-    k_pages = k_pages.at[pid, slot].set(k_new)
-    v_pages = v_pages.at[pid, slot].set(v_new)
+        k_scale = k_scale.at[layer, spid].set(jnp.where(
+            is_slot0[:, None], quant.slot0_scale(k_new, spec), k_scale[layer, 0]
+        ))
+        v_scale = v_scale.at[layer, spid].set(jnp.where(
+            is_slot0[:, None], quant.slot0_scale(v_new, spec), v_scale[layer, 0]
+        ))
+        k_new = quant.quantize_rows(k_new, k_scale[layer, pid], spec)
+        v_new = quant.quantize_rows(v_new, v_scale[layer, pid], spec)
+    k_pages = k_pages.at[layer, pid, slot].set(k_new)
+    v_pages = v_pages.at[layer, pid, slot].set(v_new)
 
     o = varlen_attention(
         q[0], k_pages, v_pages, tbl, seq_ids, positions, kv_len,
         impl=cfg.attn_impl, block_q=block_q,
-        k_scale=k_scale, v_scale=v_scale,
+        k_scale=k_scale, v_scale=v_scale, layer=layer,
     )
     o = o.reshape(1, t, cfg.n_heads * hd)
     y = jnp.einsum("bsh,hd->bsd", o, p["wo"].astype(cdt))
@@ -736,7 +742,7 @@ def forward_packed(
     positions = jnp.asarray(positions, jnp.int32)
     h = embed_lookup(params["embed"], tokens[None], cfg.compute_dtype)  # [1, T, D]
 
-    def block_step(bp, bc, h, pattern):
+    def block_step(bp, bc, h, pattern, layer):
         new_bc = {}
         for j, spec in enumerate(pattern):
             mixer, ffn = spec
@@ -744,7 +750,7 @@ def forward_packed(
             x = rms_norm(h, bpj["norm1"], cfg.norm_eps)
             y, nc = _packed_attn(
                 bpj["mixer"], x, cfg, mixer, bcj, positions, seq_ids, kv_len,
-                block_q if block_q is not None else cfg.attn_block_q,
+                block_q if block_q is not None else cfg.attn_block_q, layer,
             )
             h = h + y
             if ffn != "none":
@@ -769,11 +775,11 @@ def forward_packed(
     return logits, new_cache
 
 
-def _decode_block(bp, h, cfg, spec, cache, pos):
+def _decode_block(bp, h, cfg, spec, cache, pos, layer):
     mixer, ffn = spec
     x = rms_norm(h, bp["norm1"], cfg.norm_eps)
     if mixer.startswith("attn"):
-        y, new_cache = _decode_attn(bp["mixer"], x, cfg, mixer, cache, pos)
+        y, new_cache = _decode_attn(bp["mixer"], x, cfg, mixer, cache, pos, layer)
     elif mixer == "ssm":
         y, new_cache = m2.decode_mamba2(bp["mixer"], x, cache, cfg)
     elif mixer == "rglru":
@@ -791,56 +797,79 @@ def _decode_block(bp, h, cfg, spec, cache, pos):
     return h, new_cache
 
 
+# A paged cache's page pool and its scales: leaves with no batch axis,
+# read and written in place as one stack over the layers.
+_POOL_LEAVES = ("k_pages", "v_pages", "k_scale", "v_scale")
+
+
+def _map_cache(fn, pool_fn, cache, *rest):
+    """`tree_map` over cache trees: `pool_fn` on the paged pool leaves
+    (`_POOL_LEAVES`), `fn` on every other leaf."""
+    from jax import tree_util as jtu
+
+    def apply(path, *xs):
+        name = next(
+            (e.key for e in reversed(path) if isinstance(e, jtu.DictKey)), None
+        )
+        return (pool_fn if name in _POOL_LEAVES else fn)(*xs)
+
+    return jtu.tree_map_with_path(apply, cache, *rest)
+
+
 def _run_cached_groups(params: dict, cache: dict, h, cfg: ModelConfig, block_step):
-    """Run every stacked block group through `block_step(bp, bc, h, pattern)
-    → (h, new_bc)`, carrying the cache. The layer loop is a `fori_loop`
-    that CARRIES the stacked cache and updates each layer's slice in place
-    (`dynamic_update_index_in_dim`) — passing caches through scan xs/ys
-    would materialize input + output + working copies (measured: 19 GiB
-    temp vs ~0 on deepseek-7b decode_32k) and defeat buffer donation.
-    Shared by `decode_step_lm` (one token) and `forward_packed` (a packed
-    varlen batch) — the loop does not care how wide the token axis is."""
+    """Run every stacked block group through `block_step(bp, bc, h,
+    pattern, layer) → (h, new_bc)`, carrying the cache. The layer loop is
+    a `fori_loop` that CARRIES the stacked cache — passing caches through
+    scan xs/ys would materialize input + output + working copies
+    (measured: 19 GiB temp vs ~0 on deepseek-7b decode_32k) and defeat
+    buffer donation. Shared by `decode_step_lm` (one token) and
+    `forward_packed` (a packed varlen batch) — the loop does not care how
+    wide the token axis is.
 
-    def run_group(key, pattern):
-        nonlocal h
-        stacked_p, stacked_c = params[key], cache[key]
-        nb = jax.tree.leaves(stacked_p)[0].shape[0]
-        if not cfg.scan_layers:
-            outs = []
-            for i in range(nb):
-                h, nc = block_step(
-                    jax.tree.map(lambda x: x[i], stacked_p),
-                    jax.tree.map(lambda x: x[i], stacked_c),
-                    h, pattern,
-                )
-                outs.append(nc)
-            return jax.tree.map(lambda *ys: jnp.stack(ys), *outs)
+    Paged pool leaves (`_POOL_LEAVES`) are never sliced: `block_step`
+    gets them whole and stacked, writes the step's new rows into layer
+    `layer` of them, and the kernels read that layer through their index
+    maps — so XLA updates the carried pool in place and no layer's pool
+    is copied out and back (DESIGN.md §3.4). Every other leaf (ring
+    regions, recurrent state, block tables) is small per layer: it goes
+    in as layer `layer`'s slice and comes back with
+    `dynamic_update_index_in_dim`."""
 
+    def run_group(stacked_p, stacked_c, h, pattern):
         def body(i, carry):
             h, cache_st = carry
             bp = jax.tree.map(
                 lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False),
                 stacked_p,
             )
-            bc = jax.tree.map(
+            bc = _map_cache(
                 lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, keepdims=False),
-                cache_st,
+                lambda x: x, cache_st,
             )
-            h, nc = block_step(bp, bc, h, pattern)
-            cache_st = jax.tree.map(
+            h, nc = block_step(bp, bc, h, pattern, i)
+            cache_st = _map_cache(
                 lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
-                cache_st, nc,
+                lambda c, n: n, cache_st, nc,
             )
-            return (h, cache_st)
+            return h, cache_st
 
-        h, new_c = jax.lax.fori_loop(0, nb, body, (h, stacked_c))
-        return new_c
+        nb = jax.tree.leaves(stacked_p)[0].shape[0]
+        if cfg.scan_layers:
+            return jax.lax.fori_loop(0, nb, body, (h, stacked_c))
+        carry = (h, stacked_c)
+        for i in range(nb):  # unrolled: the same body, a static layer index
+            carry = body(i, carry)
+        return carry
 
     new_cache = {}
     if cfg.n_blocks > 0:
-        new_cache["blocks"] = run_group("blocks", cfg.pattern)
+        h, new_cache["blocks"] = run_group(
+            params["blocks"], cache["blocks"], h, cfg.pattern
+        )
     if cfg.remainder:
-        new_cache["rem_blocks"] = run_group("rem_blocks", cfg.remainder)
+        h, new_cache["rem_blocks"] = run_group(
+            params["rem_blocks"], cache["rem_blocks"], h, cfg.remainder
+        )
     return h, new_cache
 
 
@@ -848,10 +877,12 @@ def decode_step_lm(params: dict, cache: dict, token: jax.Array, pos: jax.Array, 
     """One decode step. token [B], pos [B] → (logits [B, Vpad], new cache)."""
     h = embed_lookup(params["embed"], token[:, None], cfg.compute_dtype)
 
-    def block_step(bp, bc, h, pattern):
+    def block_step(bp, bc, h, pattern, layer):
         new_bc = {}
         for j, spec in enumerate(pattern):
-            h, nc = _decode_block(bp[f"pos{j}"], h, cfg, spec, bc[f"pos{j}"], pos)
+            h, nc = _decode_block(
+                bp[f"pos{j}"], h, cfg, spec, bc[f"pos{j}"], pos, layer
+            )
             new_bc[f"pos{j}"] = nc
         return h, new_bc
 
@@ -865,24 +896,16 @@ def decode_step_lm(params: dict, cache: dict, token: jax.Array, pos: jax.Array, 
 def _freeze_dead_rows(new_cache: dict, old_cache: dict, alive: jax.Array):
     """Keep only live batch rows' cache updates: per-batch leaves (batch on
     axis 1 after block stacking) revert to the old value where ¬alive; POOL
-    leaves (`k_pages`/`v_pages`, no batch axis) pass through — a dead row's
+    leaves (`_POOL_LEAVES`, no batch axis) pass through — a dead row's
     page writes land in slots beyond its effective length, which decode
     overwrites before it ever reads them (the bucketed-prefill argument in
     DESIGN.md §3.5)."""
-    from jax import tree_util as jtu
-
-    def leaf_name(path):
-        for e in reversed(path):
-            if isinstance(e, jtu.DictKey):
-                return e.key
-        return None
-
-    def apply(path, new, old):
-        if leaf_name(path) in ("k_pages", "v_pages", "k_scale", "v_scale"):
-            return new
-        return jnp.where(alive.reshape((1, -1) + (1,) * (new.ndim - 2)), new, old)
-
-    return jtu.tree_map_with_path(apply, new_cache, old_cache)
+    return _map_cache(
+        lambda new, old: jnp.where(
+            alive.reshape((1, -1) + (1,) * (new.ndim - 2)), new, old
+        ),
+        lambda new, old: new, new_cache, old_cache,
+    )
 
 
 def prefill_lm(params: dict, tokens: jax.Array, cache: dict, cfg: ModelConfig,

@@ -36,6 +36,7 @@ from repro.kernels.flashd_decode import (
     flashd_decode_pallas,
 )
 from repro.kernels.ref import decode_ref
+from repro.runtime import quant
 
 _F32_TOL = 1e-4  # acceptance bound; observed agreement is ~2 f32 ulps
 
@@ -136,6 +137,71 @@ def test_paged_jnp_route_matches_kernel(seed, group, page, n_tbl):
     np.testing.assert_array_equal(
         np.asarray(kc),
         np.asarray(k_pages)[np.asarray(tbl)].reshape(b, n_tbl * page, hkv, d),
+    )
+
+
+def _stack(x, layer, n_layers):
+    """x as layer `layer` of an [n_layers, ...] stack whose other layers
+    hold other pages (x rolled along its page axis)."""
+    return jnp.stack([jnp.roll(x, i - layer, axis=0) for i in range(n_layers)])
+
+
+def _typed_pool(kv, q, k_pages, v_pages):
+    """The case in a bf16 pool, or int8 with slot-0 scales (DESIGN.md
+    §3.8) → (q, pool dict of k_pages, v_pages[, k_scale, v_scale])."""
+    if kv == "bf16":
+        q, k_pages, v_pages = (x.astype(jnp.bfloat16) for x in (q, k_pages, v_pages))
+        return q, {"k_pages": k_pages, "v_pages": v_pages}
+    spec = quant.get_spec(kv)
+    pool = {}
+    for name, x in (("k", k_pages), ("v", v_pages)):
+        sc = quant.slot0_scale(x[:, 0], spec)
+        pool[f"{name}_pages"] = quant.quantize_rows(x, sc[:, None, :], spec)
+        pool[f"{name}_scale"] = sc
+    return q, pool
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@settings(max_examples=6, deadline=None)
+@given(
+    seed=st.integers(min_value=0, max_value=2**31 - 1),
+    group=st.sampled_from([1, 4]),
+    page=st.sampled_from([4, 8]),
+    n_tbl=st.integers(min_value=1, max_value=3),
+    n_layers=st.integers(min_value=2, max_value=3),
+    layer=st.integers(min_value=0, max_value=2),
+)
+def test_paged_decode_stacked_pool(kv, seed, group, page, n_tbl, n_layers,
+                                   layer):
+    """The model's layer loop hands the kernel the whole stacked pool
+    and a layer index (DESIGN.md §3.4): the kernel on [L, P, ...] returns
+    bit for bit what it returns on pool[layer], scales included, and so
+    does the jnp mirror; kernel and mirror agree. The drawn layer and the
+    last are read, so a kernel stuck on layer 0 shows."""
+    q, k_pages, v_pages, tbl, cl = _paged_case(
+        seed, 2, 2, group, 16, n_tbl, page, "rand"
+    )
+    q, pool = _typed_pool(kv, q, k_pages, v_pages)
+
+    def run(fn, pool, **kw):
+        return fn(pool["k_pages"], pool["v_pages"], k_scale=pool.get("k_scale"),
+                  v_scale=pool.get("v_scale"), **kw)
+
+    kern = lambda k, v, **kw: flashd_decode_paged_pallas(
+        q, k, v, tbl, cl, interpret=True, **kw)
+    mirror = lambda k, v, **kw: decode_attention_paged(
+        q[:, None], k, v, tbl, cl, **kw)[:, 0]
+    o_kern, o_mirror = run(kern, pool), run(mirror, pool)
+    for at in {layer % n_layers, n_layers - 1}:
+        stack = {n: _stack(x, at, n_layers) for n, x in pool.items()}
+        np.testing.assert_array_equal(
+            np.asarray(run(kern, stack, layer=at)), np.asarray(o_kern))
+        np.testing.assert_array_equal(
+            np.asarray(run(mirror, stack, layer=at)), np.asarray(o_mirror))
+    tol = 3e-2 if kv == "bf16" else 5e-5
+    np.testing.assert_allclose(
+        np.asarray(o_kern, np.float32), np.asarray(o_mirror, np.float32),
+        rtol=tol, atol=tol,
     )
 
 

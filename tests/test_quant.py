@@ -264,6 +264,67 @@ def test_write_determinism_sequential_vs_packed(engine_fixture):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+@pytest.mark.parametrize("step", ["decode", "packed"])
+def test_stacked_step_pins_other_layers(step, kv_dtype):
+    """A layer's step on the stacked pool (DESIGN.md §3.4) writes that
+    layer alone: with the pool three layers deep and the step at layer
+    1, the output and layer 1's new pages and scales equal the same step
+    on a one-layer pool, and layers 0 and 2 come back bit-unchanged.
+    Both steps write slot 0 of a page beside later slots, so the slot-0
+    scale rule runs on the stacked scale leaf too."""
+    from repro.models import transformer as tf
+
+    cfg = dataclasses.replace(
+        _cfg(), n_layers=3, dtype="bfloat16", attn_impl="flashd_pallas"
+    )
+    rng = np.random.default_rng(11)
+    cache = tf.init_decode_cache(
+        2, 16, cfg, layout="paged", page_size=4, kv_dtype=kv_dtype
+    )["blocks"]["pos0"]
+
+    def fill(name, x):  # distinct data in every layer: a stray write shows
+        if name == "tbl":
+            return jnp.broadcast_to(jnp.arange(1, 9).reshape(2, 4), x.shape)
+        if name.endswith("scale"):
+            return jnp.asarray(rng.uniform(0.01, 0.1, x.shape), x.dtype)
+        if x.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, x.shape), x.dtype)
+        return jnp.asarray(rng.standard_normal(x.shape), x.dtype)
+
+    stack = {n: fill(n, x) for n, x in cache.items()}
+    layer = 1
+    p = tf._init_attn(jax.random.PRNGKey(2), cfg)
+    if step == "decode":
+        x = jnp.asarray(rng.standard_normal((2, 1, cfg.d_model)), jnp.bfloat16)
+        pos = jnp.asarray([5, 8], jnp.int32)  # row 1 opens page 2 (slot 0)
+        run = lambda c, i: tf._decode_attn(p, x, cfg, "attn", c, pos, i)
+    else:
+        x = jnp.asarray(rng.standard_normal((1, 8, cfg.d_model)), jnp.bfloat16)
+        seq_ids = jnp.asarray([0, 0, 0, 0, 1, 1, -1, -1], jnp.int32)
+        positions = jnp.asarray([3, 4, 5, 6, 0, 1, -1, -1], jnp.int32)
+        kv_len = jnp.asarray([7, 2], jnp.int32)
+        run = lambda c, i: tf._packed_attn(
+            p, x, cfg, "attn", c, positions, seq_ids, kv_len, 4, i
+        )
+
+    def at_layer(c, i):  # the layer's view: pool leaves stay stacked
+        return {n: x if n != "tbl" else x[i] for n, x in c.items()}
+
+    y, new = run(at_layer(stack, layer), layer)
+    one = {n: x[layer:layer + 1] for n, x in stack.items()}
+    y1, new1 = run(at_layer(one, 0), 0)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y1))
+    for name, old in stack.items():
+        if name == "tbl":
+            continue
+        got = np.asarray(new[name])
+        np.testing.assert_array_equal(got[layer], np.asarray(new1[name][0]))
+        assert not np.array_equal(got[layer], np.asarray(old[layer]))
+        for other in (0, 2):
+            np.testing.assert_array_equal(got[other], np.asarray(old[other]))
+
+
 def test_radix_warm_hit_token_identical_int8(engine_fixture):
     """A warm radix hit replays cached quantized pages: because a donated
     page's bytes+scale are a pure function of its token prefix, the warm

@@ -8,12 +8,19 @@ the chip. The paged kernels, whose K/V blocks hold whole pages, compile at
 paper-llama widths too (MHA 6/6, d 48: a head narrower than a lane tile).
 Nothing runs, so nothing here is a time or a result.
 
+The model-step tests compile whole programs of the serving loop at the
+benchmark's pool size and count the copies of a layer's page pool they
+make: the paged kernels read the stacked pool through a layer index, so
+there must be none (DESIGN.md §3.4).
+
 The topology is described inside a module fixture (never at import): only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -180,3 +187,82 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES
+
+
+# ---------------------------------------------------------------------------
+# model step: the stacked page pool is never copied per layer
+# ---------------------------------------------------------------------------
+
+POOL_PAGES = 2049  # the benchmark's 131,072-token pool in pages of 64, + garbage
+STEP_LAYERS, STEP_SLOTS, STEP_PACK = 4, 64, 512
+
+
+def _step_program(program, kv_dtype):
+    """A serving program at qwen2-1.5b attention widths over a stack of 4
+    layers and a pool of 2,049 pages of 64: the decode chunk (the
+    engine's scan of `decode_step_lm`) or one 512-row `forward_packed`."""
+    from repro.configs.qwen2_1_5b import CONFIG
+    from repro.models import transformer as tf
+
+    cfg = dataclasses.replace(
+        CONFIG, n_layers=STEP_LAYERS, vocab_size=1024, param_dtype="bfloat16",
+        attn_impl="flashd_pallas",
+    )
+    params = jax.eval_shape(lambda: tf.init_lm(jax.random.PRNGKey(0), cfg))
+    cache = jax.eval_shape(lambda: tf.init_decode_cache(
+        STEP_SLOTS, 8192, cfg, layout="paged", page_size=PAGE,
+        n_pages=POOL_PAGES, kv_dtype=kv_dtype,
+    ))
+    i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)
+    if program == "decode_chunk":
+        def fn(p, c, tok, pos):
+            def body(carry, _):
+                c, tok, pos = carry
+                logits, c = tf.decode_step_lm(p, c, tok, pos, cfg)
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                return (c, nxt, pos + 1), nxt
+            return jax.lax.scan(body, (c, tok, pos), None, length=8)
+
+        return fn, (params, cache, i32(STEP_SLOTS), i32(STEP_SLOTS))
+
+    def fn(p, c, tokens, seq_ids, positions, kv_len, rows):
+        return tf.forward_packed(
+            p, tokens, seq_ids, positions, kv_len, c, cfg, rows, block_q=8
+        )
+
+    return fn, (params, cache, *[i32(STEP_PACK)] * 3, i32(STEP_SLOTS),
+                i32(STEP_SLOTS))
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+@pytest.mark.parametrize("program", ["decode_chunk", "forward_packed"])
+def test_step_never_copies_a_layer_pool(one_chip, monkeypatch, program,
+                                        kv_dtype):
+    """No dynamic-slice or dynamic-update-slice of the compiled program
+    has a layer pool's shape [.., 2049, 64, 2, 128]: the layer loop carries
+    the stacked pool whole, scatters the step's rows into it in place, and
+    the kernels pick their layer by scalar prefetch. In a bf16 pool the
+    program's temp stays under one layer's K pool (67 MB; a layer
+    copied out and back needed 87 MB). An int8 pool's default TPU layout
+    is not the kernels' row-major one, so XLA relayouts the whole stack
+    at the program's entry and exit — once per program, not per layer —
+    and its temp holds those copies."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "interpret_mode", lambda: False)
+    fn, args = _step_program(program, kv_dtype)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args,
+    )
+    compiled = jax.jit(fn).lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    pool = f"{POOL_PAGES},{PAGE},{HKV},{D}"
+    layer_copies = re.findall(
+        rf"= \w+\[(?:\d+,)*{pool}\]\S* dynamic-(?:update-)?slice\(", hlo
+    )
+    assert layer_copies == []
+    if not kv_dtype:
+        layer_k_pool = POOL_PAGES * PAGE * HKV * D * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_k_pool

@@ -31,6 +31,7 @@ from repro.core.attention import (
     gather_pages,
     varlen_attention,
 )
+from repro.runtime import quant
 
 _TOL = 1e-4  # observed agreement is a few f32 ulps
 
@@ -202,6 +203,57 @@ def test_varlen_decode_rows_vs_decode_attention(seed, n_seqs, hkv, group, d,
         np.testing.assert_allclose(
             np.asarray(o[off]), np.asarray(want[0, 0]), atol=_TOL, rtol=_TOL,
         )
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@settings(max_examples=6, deadline=None)
+@given(
+    seed=st.integers(min_value=0, max_value=2**31 - 1),
+    n_seqs=st.integers(min_value=1, max_value=3),
+    group=st.sampled_from([1, 4]),
+    n_tbl=st.integers(min_value=1, max_value=3),
+    page=st.sampled_from([4, 8]),
+    n_layers=st.integers(min_value=2, max_value=3),
+    layer=st.integers(min_value=0, max_value=2),
+)
+def test_varlen_stacked_pool(kv, seed, n_seqs, group, n_tbl, page, n_layers,
+                             layer):
+    """The packed step hands the kernel the whole stacked pool and a
+    layer index (DESIGN.md §3.4): on [L, P, ...] the kernel returns bit
+    for bit what it returns on pool[layer] — scales included — and so
+    does the jnp mirror; kernel and mirror agree. The other layers hold
+    other pages and the drawn layer and the last are read, so reading
+    the wrong one shows."""
+    q, kp, vp, tbl, sids, qpos, kvl, _ = _varlen_case(
+        seed, n_seqs, 2, group, 16, n_tbl, page, 4,
+        kinds=("prefill", "decode", "empty"),
+    )
+    if kv == "bf16":
+        q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+        pool = {"k_pages": kp, "v_pages": vp}
+    else:
+        spec = quant.get_spec(kv)
+        ks, vs = (quant.slot0_scale(x[:, 0], spec) for x in (kp, vp))
+        pool = {"k_pages": quant.quantize_rows(kp, ks[:, None, :], spec),
+                "v_pages": quant.quantize_rows(vp, vs[:, None, :], spec),
+                "k_scale": ks, "v_scale": vs}
+    def run(impl, pool, **kw):
+        return np.asarray(varlen_attention(
+            q, pool["k_pages"], pool["v_pages"], tbl, sids, qpos, kvl,
+            impl=impl, block_q=4, k_scale=pool.get("k_scale"),
+            v_scale=pool.get("v_scale"), **kw,
+        ), np.float32)
+
+    o_kern, o_mirror = run("flashd_pallas", pool), run("flashd", pool)
+    for at in {layer % n_layers, n_layers - 1}:
+        stack = {n: jnp.stack([jnp.roll(x, i - at, axis=0)
+                               for i in range(n_layers)])
+                 for n, x in pool.items()}
+        np.testing.assert_array_equal(
+            run("flashd_pallas", stack, layer=at), o_kern)
+        np.testing.assert_array_equal(run("flashd", stack, layer=at), o_mirror)
+    tol = 3e-2 if kv == "bf16" else 5e-5
+    np.testing.assert_allclose(o_kern, o_mirror, rtol=tol, atol=tol)
 
 
 def test_varlen_mixed_pack_three_way():
