@@ -1,14 +1,17 @@
-"""Plain reference of the qwen2 / qwen3 decoder stack, in float32.
+"""The dense GQA decoder (qwen2, qwen3): its plain float32 reference and
+the counts of its work. A configuration names it with `"arch":
+"dense_gqa"`; `harness/cells.py` loads it by path.
 
-It imports nothing of the program. It reads the benchmark's weight tree
-by name (`embed`, `final_norm`, optional `lm_head`, and per layer, stacked
-on axis 0 under `blocks/pos0`: `norm1`, `norm2`, `mixer/{wq,wk,wv,wo}`,
-optional `mixer/{bq,bk,bv}` and `mixer/{q_norm,k_norm}`,
-`ffn/{wg,wu,wd}`), and follows the published architecture: grouped-query
-attention with causal masking, qkv bias (qwen2), RMSNorm of q and k per
-head (qwen3), rotary embeddings on the two halves of each head, RMSNorm
-before attention and the feed-forward, and SwiGLU. Norm scales are
-applied as (1 + w), the parametrisation the weight tree stores them in.
+Reference. It imports nothing of the program. It reads the benchmark's
+weight tree by name (`embed`, `final_norm`, optional `lm_head`, and per
+layer, stacked on axis 0 under `blocks/pos0`: `norm1`, `norm2`,
+`mixer/{wq,wk,wv,wo}`, optional `mixer/{bq,bk,bv}` and
+`mixer/{q_norm,k_norm}`, `ffn/{wg,wu,wd}`), and follows the published
+architecture: grouped-query attention with causal masking, qkv bias
+(qwen2), RMSNorm of q and k per head (qwen3), rotary embeddings on the
+two halves of each head, RMSNorm before attention and the feed-forward,
+and SwiGLU. Norm scales are applied as (1 + w), the parametrisation the
+weight tree stores them in.
 
 Every product runs at `precision="highest"`. `lowp="fp8"` rounds both
 operands of every product to float8 (e4m3, one scale per tensor): the
@@ -17,6 +20,14 @@ control, one precision below what the configurations state.
 Memory stays bounded at the timed sizes: attention runs in blocks of
 query rows, layers are rematerialised in the backward pass, and the loss
 and the logits are taken in blocks of rows.
+
+Counts (`bench/harness/work.py` asks for them). `model` is a
+configuration's `model` dict. A multiply-add counts 2 FLOPs. Attention
+work is the causal minimum: a query at position p reads keys 0..p; the
+bytes are what the algorithm must move at least (each prefill chunk
+reads its context's K/V once, each decoded token reads its whole cache
+once, training reads and writes each operand once), in the served dtype
+(bf16, 2 bytes).
 """
 
 from __future__ import annotations
@@ -27,6 +38,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 HIGHEST = lax.Precision.HIGHEST
@@ -59,6 +71,10 @@ class Arch:
             tie_embeddings=bool(model.get("tie_embeddings", False)),
             lowp=lowp,
         )
+
+    def visible(self, rows, keys):
+        """[Q, K] mask of the keys each query row attends to: causal."""
+        return keys[None, :] <= rows[:, None]
 
 
 def _round(x, dtype, top):
@@ -122,7 +138,7 @@ def _attention(arch: Arch, q, k, v):
         i, qi = args  # qi [B, Q, Hkv, g, d]
         sc = _mm(arch, "bqhgd,bkhd->bhgqk", qi, k) / jnp.sqrt(jnp.float32(d))
         rows = i * Q_BLOCK + jnp.arange(Q_BLOCK)
-        sc = jnp.where(keys[None, :] <= rows[:, None], sc, -jnp.inf)
+        sc = jnp.where(arch.visible(rows, keys), sc, -jnp.inf)
         p = jax.nn.softmax(sc, axis=-1)
         return _mm(arch, "bhgqk,bkhd->bqhgd", p, v)
 
@@ -157,8 +173,11 @@ def _layer(arch: Arch, lp, h, pos):
 def _check_tree(params):
     extra = set(params) - {"embed", "final_norm", "lm_head", "blocks"}
     if extra or set(params["blocks"]) != {"pos0"}:
-        raise ValueError(f"reference covers one repeated (attn, swiglu) layer; "
-                         f"tree has {sorted(params)} / {sorted(params['blocks'])}")
+        raise ValueError(
+            f"dense_gqa covers one repeated (attn, swiglu) layer; the tree has "
+            f"{sorted(params)} / {sorted(params['blocks'])}: write bench/arch/"
+            f"<name>.py for this architecture and name it in the "
+            f"configuration's \"arch\"")
 
 
 def hidden(arch: Arch, params, tokens):
@@ -209,38 +228,57 @@ def loss(arch: Arch, params, tokens, labels):
     return jnp.sum(tot) / jnp.maximum(jnp.sum(cnt), 1.0)
 
 
-# ---- the optimizer the training configurations state -----------------------
+# ---- counts ------------------------------------------------------------------
 
-def lr_at(opt: dict, step):
-    """Linear warm-up from 0, then cosine to `final_frac` of the peak."""
-    step = jnp.asarray(step, jnp.float32)
-    peak, warm, total = opt["lr"], opt["warmup_steps"], opt["total_steps"]
-    t = jnp.clip((step - warm) / max(total - warm, 1), 0.0, 1.0)
-    cos = peak * (opt["final_frac"] + (1 - opt["final_frac"]) * 0.5 * (1 + jnp.cos(jnp.pi * t)))
-    return jnp.where(step < warm, peak * step / max(warm, 1), cos)
+KV_BYTES = 2
 
 
-def decays(opt: dict, name: str, ndim: int) -> bool:
-    return ndim > 1 and not any(k in name for k in opt["no_decay_keywords"])
+def matmul_params_per_layer(m: dict) -> int:
+    d, hd, ff = m["d_model"], m["head_dim"], m["d_ff"]
+    hq, hkv = m["n_heads"], m["n_kv_heads"]
+    return d * hq * hd + 2 * d * hkv * hd + hq * hd * d + 3 * d * ff
 
 
-def adamw(opt: dict, params, grads, m, v, step: int):
-    """One AdamW step after global-norm clipping; `step` counts from 0.
-    Returns (params, m, v, clipped grads)."""
-    leaves = jax.tree.leaves(grads)
-    gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in leaves))
-    scale = jnp.minimum(1.0, opt["clip_norm"] / jnp.maximum(gnorm, 1e-9))
-    g = jax.tree.map(lambda x: x * scale, grads)
-    b1, b2, t = opt["b1"], opt["b2"], step + 1
-    m = jax.tree.map(lambda a, x: b1 * a + (1 - b1) * x, m, g)
-    v = jax.tree.map(lambda a, x: b2 * a + (1 - b2) * x * x, v, g)
-    lr = lr_at(opt, step)
+def flops_per_token(m: dict) -> int:
+    """Forward FLOPs of one token through every layer's matmuls."""
+    return 2 * m["n_layers"] * matmul_params_per_layer(m)
 
-    def upd(path, p, a, b):
-        name = "/".join(str(getattr(k, "key", k)) for k in path)
-        wd = opt["weight_decay"] if decays(opt, name, p.ndim) else 0.0
-        u = (a / (1 - b1 ** t)) / (jnp.sqrt(b / (1 - b2 ** t)) + opt["eps"]) + wd * p
-        return p - lr * u
 
-    params = jax.tree_util.tree_map_with_path(upd, params, m, v)
-    return params, m, v, g
+def head_flops(m: dict) -> int:
+    """Forward FLOPs of one row of logits."""
+    return 2 * m["d_model"] * m["vocab_size"]
+
+
+def attn_flops(m: dict, ctx_sum):
+    """Forward attention FLOPs over every layer, for queries whose
+    context lengths sum to ctx_sum (QK^T and PV)."""
+    return 4.0 * m["n_layers"] * m["n_heads"] * m["head_dim"] * ctx_sum
+
+
+def causal_ctx_sum(start, n):
+    """Sum of (p + 1) for positions p in [start, start + n)."""
+    return n * start + n * (n + 1) / 2.0
+
+
+def mixer_work(m: dict, start, n):
+    """(FLOPs, bytes) over every layer of the attention forward of pieces
+    of one sequence: piece i puts n[i] queries at positions
+    [start[i], start[i] + n[i]) against the K/V of every position before
+    them and its own (a prefill chunk, or a decoded token with n 1)."""
+    start, n = np.asarray(start, np.float64), np.asarray(n, np.float64)
+    L, hq, hkv, hd = m["n_layers"], m["n_heads"], m["n_kv_heads"], m["head_dim"]
+    kv_row = 2 * hkv * hd * KV_BYTES  # K and V of one position
+    q_row = 2 * hq * hd * KV_BYTES  # q read and o written
+    return (attn_flops(m, causal_ctx_sum(start, n)),
+            L * ((start + n) * kv_row + n * q_row))
+
+
+def train_mixer_work(m: dict, batch: int, seq_len: int):
+    """(FLOPs, bytes) of one step's attention, forward and backward: 3x
+    the forward FLOPs; q, k, v and o read or written once forward, and
+    q, k, v, o, do read and dq, dk, dv written backward."""
+    fwd = batch * attn_flops(m, causal_ctx_sum(0, seq_len))
+    rows = batch * seq_len * m["n_layers"] * m["head_dim"] * KV_BYTES
+    hq, hkv = m["n_heads"], m["n_kv_heads"]
+    nbytes = rows * ((2 * hq + 2 * hkv) + (3 * hq + 2 * hkv) + (hq + 2 * hkv))
+    return np.array([3.0 * fwd]), np.array([float(nbytes)])

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from harness import reference as ref
+from harness import optimizer
 from harness.traffic import rng_for
 
 SEQ_BUCKET = 1024
@@ -49,12 +49,12 @@ def serve_sample(prompts, outputs, seed: int, k: int) -> List[int]:
 
 
 @functools.lru_cache(maxsize=None)
-def _hidden_fn(arch):
+def _hidden_fn(ref, arch):
     return jax.jit(lambda p, t: ref.hidden(arch, p, t))
 
 
 @functools.lru_cache(maxsize=None)
-def _gap_fn(arch, ctrl):
+def _gap_fn(ref, arch, ctrl):
     """Per row: best reference logit minus the reference logit of the
     served token, and (with a control arch) of the control's first token."""
 
@@ -70,8 +70,10 @@ def _gap_fn(arch, ctrl):
     return jax.jit(fn)
 
 
-def serve_gap(model: dict, params, pairs: Sequence[Tuple[np.ndarray, np.ndarray]],
+def serve_gap(ref, model: dict, params,
+              pairs: Sequence[Tuple[np.ndarray, np.ndarray]],
               lowp=None) -> Dict[str, float]:
+    """`ref` is the configuration's architecture module."""
     arch = ref.Arch.of(model)
     ctrl = ref.Arch.of(model, lowp) if lowp else None
     worst, worst_ctrl, tokens = 0.0, 0.0, 0
@@ -79,8 +81,8 @@ def serve_gap(model: dict, params, pairs: Sequence[Tuple[np.ndarray, np.ndarray]
         seq = np.concatenate([prompt, out[:-1]]).astype(np.int32)
         n_pad = -(-len(seq) // SEQ_BUCKET) * SEQ_BUCKET
         toks = jnp.asarray(np.pad(seq, (0, n_pad - len(seq)))[None])
-        h = _hidden_fn(arch)(params, toks)[0]
-        h_ctrl = _hidden_fn(ctrl)(params, toks)[0] if ctrl else h
+        h = _hidden_fn(ref, arch)(params, toks)[0]
+        h_ctrl = _hidden_fn(ref, ctrl)(params, toks)[0] if ctrl else h
         start = len(prompt) - 1
         for r in range(0, len(out), ROWS):
             rows = np.arange(start + r, start + r + ROWS)
@@ -88,7 +90,7 @@ def serve_gap(model: dict, params, pairs: Sequence[Tuple[np.ndarray, np.ndarray]
             n = min(ROWS, len(out) - r)
             served[:n] = out[r:r + n]
             rows = np.minimum(rows, n_pad - 1)
-            g, gc = _gap_fn(arch, ctrl)(params, h[rows], jnp.asarray(served),
+            g, gc = _gap_fn(ref, arch, ctrl)(params, h[rows], jnp.asarray(served),
                                         h_ctrl[rows])
             worst = max(worst, float(jnp.max(g[:n])))
             worst_ctrl = max(worst_ctrl, float(jnp.max(gc[:n])))
@@ -140,28 +142,34 @@ def moved_leaves(grad_norms: Dict[str, float]) -> set:
     return {n for n, g in grad_norms.items() if g >= 1e-3 * med}
 
 
-def train_reference(model: dict, opt: dict, params, batches, steps: int,
-                    delta_norms, lowp=None, half_batch=False) -> dict:
+def train_reference(ref, model: dict, opt: dict, params, batches, steps: int,
+                    delta_norms, lowp=None, half_batch=False,
+                    shardings=None) -> dict:
     """Losses, the first clipped gradient's leaf norms, Adam's first
     moment after one step (the first clipped gradient times 1 - b1) on the
     host, and the change's leaf norms (`delta_norms(params)`, against the
     seed's initial weights) after `steps` AdamW steps of the plain
-    reference from `params`, which it consumes. `half_batch` plants a fault: each loss is the mean over
-    the first half of the batch's tokens only."""
+    reference (`ref`, the configuration's architecture module) from
+    `params`, which it consumes. `shardings` (a tree like `params`, or
+    None on one device) is the layout the weights were drawn in; the
+    step keeps the weights and both moments on it. `half_batch` plants a
+    fault: each loss is the mean over the first half of the batch's
+    tokens only."""
     arch = ref.Arch.of(model, lowp)
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+    @functools.partial(jax.jit, donate_argnums=(0, 1, 2),
+                       out_shardings=(shardings, shardings, shardings, None, None))
     def step(p, m, v, tokens, labels, i):
         if half_batch:
             n = labels.shape[0] * labels.shape[1]
             keep = (jnp.arange(n) < n // 2).reshape(labels.shape)
             labels = jnp.where(keep, labels, -1)
         loss, g = jax.value_and_grad(lambda q: ref.loss(arch, q, tokens, labels))(p)
-        p, m, v, gc = ref.adamw(opt, p, g, m, v, i)
+        p, m, v, gc = optimizer.adamw(opt, p, g, m, v, i)
         return p, m, v, loss, leaf_norms(gc)
 
-    m = jax.tree.map(jnp.zeros_like, params)
-    v = jax.tree.map(jnp.zeros_like, params)
+    zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p), out_shardings=shardings)
+    m, v = zeros(params), zeros(params)
     p, losses, grad_norms, moment = params, [], None, None
     del params
     for i in range(steps):

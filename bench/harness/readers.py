@@ -5,15 +5,56 @@ trace holds nothing to divide by, never 0."""
 
 from __future__ import annotations
 
+import re
+
 import numpy as np
 
 from harness import trace as tracing
 from harness import work as W
 
+COLLECTIVE = re.compile(
+    r"^(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)(-start|-done)?$")
+# ops whose event spans the ops of their body (a scanned layer loop)
+CONTAINERS = ("while", "conditional", "call")
+
 def is_pallas(name: str) -> bool:
-    """A Pallas (Mosaic) kernel event. The program names no kernel yet,
-    so every such event counts as attention in the dense cells."""
+    """A Pallas (Mosaic) kernel event. The program names its kernels
+    (`flashd_varlen`, `flashd_fwd`, ...), but every Pallas event still
+    counts as attention: in the dense cells all of them are."""
     return 'custom_call_target="tpu_custom_call"' in name
+
+def _opcode(name: str) -> str:
+    m = tracing._HLO.match(name)
+    return m.group(2) if m else ""
+
+def collective_share(ctx):
+    """Percent of the traced window in which a collective op (by its HLO
+    opcode: all-reduce, all-gather, reduce-scatter, all-to-all,
+    collective-permute, or the -start or -done half of one) ran on a chip
+    and no other op did, on the chip where that is largest; None where
+    the trace holds no collective. A loop's or call's own event spans its
+    body and is no other op here. Notes each chip's exposed and total
+    collective time."""
+    tr = ctx.trace
+    if tr.window_s <= 0:
+        return None
+    per_chip = []
+    for d in tr.devices:
+        ops = [_opcode(n) for n in d.op_names]
+        coll = np.array([bool(COLLECTIVE.match(o)) for o in ops], bool)
+        if not coll.any():
+            continue
+        other = ~coll & np.array([o not in CONTAINERS for o in ops], bool)
+        exposed = tracing.exclusive_ns(d.ops[coll], d.ops[other])
+        per_chip.append((exposed, tracing.sum_ns(d.ops[coll]), d.name))
+    if not per_chip:
+        return None
+    worst = max(per_chip)
+    ctx.notes = getattr(ctx, "notes", []) + [
+        "collectives (exposed / total op time): " + ", ".join(
+            f"{name} {e * 1e-9:.4f}s / {t * 1e-9:.4f}s" for e, t, name in per_chip)
+        + f"; worst {worst[2]}"]
+    return 100.0 * worst[0] / (tr.window_s * 1e9)
 
 def kernel_s(ctx) -> float:
     return sum(tracing.sum_ns(d.select(is_pallas)) for d in ctx.trace.devices) * 1e-9

@@ -36,9 +36,14 @@ POOL_SAMPLE_S = 0.2
 
 
 def model_config(config: dict):
+    """The program's ModelConfig of a configuration's `model` (a layer
+    `pattern` comes from JSON as lists and is made tuples)."""
     from repro.models.config import ModelConfig
 
-    return ModelConfig(name=config["name"], **config["model"])
+    model = dict(config["model"])
+    if "pattern" in model:
+        model["pattern"] = tuple(tuple(spec) for spec in model["pattern"])
+    return ModelConfig(name=config["name"], **model)
 
 
 def engine_settings(config: dict, mix: dict, max_len: int, seed: int) -> dict:
@@ -157,7 +162,8 @@ class ServeCell:
         vocab = self.mc.vocab_size
         self.traffic = traffic.serve_traffic(cell.mix, seed, seconds, vocab)
         self.shapes = weights.param_shapes(self.mc)
-        params = weights.make_params(self.shapes, seed, self.mc.param_dtype)
+        params = weights.make_params(self.shapes, seed, self.mc.param_dtype,
+                                     arch=cell.arch)
         jax.block_until_ready(params)
         phases.lap("weights")
         p = cell.mix["prompt"]
@@ -206,8 +212,8 @@ class ServeCell:
                         dict(st["request_status"]), wall, st)
 
     def work(self, run: ServeRun) -> ServeWork:
-        return ServeWork.of(self.cell.config["model"], run.prompts, run.outputs,
-                            self.engine_cfg["prefill_chunk"])
+        return ServeWork.of(self.cell.arch, self.cell.config["model"], run.prompts,
+                            run.outputs, self.engine_cfg["prefill_chunk"])
 
     def free(self) -> None:
         self.engine = None
@@ -216,9 +222,10 @@ class ServeCell:
     def check(self, run: ServeRun, lowp=None) -> dict:
         """The widest gap of the sample (and of the control, with
         `lowp`), after the program's state is freed."""
-        params = weights.make_params(self.shapes, self.seed, self.mc.param_dtype)
+        params = weights.make_params(self.shapes, self.seed, self.mc.param_dtype,
+                                     arch=self.cell.arch)
         sample = check.serve_sample(run.prompts, run.outputs, self.seed,
                                     self.cell.limits["sample_requests"])
-        return check.serve_gap(self.cell.config["model"], params,
+        return check.serve_gap(self.cell.arch, self.cell.config["model"], params,
                                [(run.prompts[i], run.outputs[i]) for i in sample],
                                lowp=lowp)

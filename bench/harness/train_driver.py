@@ -123,10 +123,20 @@ class TrainCell:
                              devices=jax.devices()[: cell.chips])
         init_state_fn, self.step_fn = build_trainer(self.mc, self.tc, seed=0,
                                                     mesh=mesh)
-        shapes = jax.eval_shape(init_state_fn)
+        if mesh is None:
+            shapes = jax.eval_shape(init_state_fn)
+        else:
+            # the program's sharded init enters its mesh (`jax.set_mesh`),
+            # which no trace allows, whether or not the mesh is entered
+            # around `eval_shape`: it runs once, and only its shapes and
+            # layout stay
+            shapes = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
+                init_state_fn())
         self.param_shapes = shapes.params
         params = weights.make_params(shapes.params, seed, jnp.float32,
-                                     out_shardings=_shardings(shapes.params))
+                                     out_shardings=_shardings(shapes.params),
+                                     arch=cell.arch)
         rest = jax.jit(
             lambda: jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
                                  shapes._replace(params=None)),
@@ -146,7 +156,7 @@ class TrainCell:
         out = {}
         for path, x in flat:
             name = weights.path_name(path)
-            x0 = weights.init_leaf(name, x.shape, words, jnp.float32)
+            x0 = weights.init_leaf(name, x.shape, words, jnp.float32, self.cell.arch)
             out[name] = jnp.sqrt(jnp.sum(jnp.square(x - x0)))
         return out
 
@@ -221,13 +231,21 @@ class TrainCell:
         return log
 
     def work(self) -> TrainWork:
-        return TrainWork(self.cell.config["model"],
+        return TrainWork(self.cell.arch, self.cell.config["model"],
                          self.cell.config["deployment"]["global_batch"],
                          self.cell.mix["seq_len"])
 
     def reference(self, lowp=None, half_batch=False) -> dict:
-        params = weights.make_params(self.param_shapes, self.seed, jnp.float32)
+        """The plain reference's readings from the seed's weights: on one
+        chip there; on several, laid over the cell's chips by the
+        benchmark's own layout (`weights.spread`)."""
+        layout = None
+        if self.cell.chips > 1:
+            layout = weights.spread(self.param_shapes, jax.devices()[: self.cell.chips])
+        params = weights.make_params(self.param_shapes, self.seed, jnp.float32,
+                                     out_shardings=layout, arch=self.cell.arch)
         batches = [self.data.data.batch(i) for i in range(CHECKED_STEPS)]
         return check.train_reference(
-            self.cell.config["model"], self.opt, params, batches,
-            CHECKED_STEPS, self.delta_norms, lowp=lowp, half_batch=half_batch)
+            self.cell.arch, self.cell.config["model"], self.opt, params, batches,
+            CHECKED_STEPS, self.delta_norms, lowp=lowp, half_batch=half_batch,
+            shardings=layout)

@@ -54,3 +54,36 @@ def test_reduction_of_a_recorded_chip_trace():
     assert len(kernels) > 0
     assert T.sum_ns(kernels) < T.sum_ns(d.ops)
     assert T.union_ns(d.modules) >= T.union_ns(kernels)
+
+
+def _hlo(name, opcode):
+    return f"%{name}.1 = bf16[8,1536]{{1,0}} {opcode}(%p.2), replica_groups={{{{0,1,2,3}}}}"
+
+
+def test_collective_share_counts_exposed_time_on_the_worst_chip():
+    from harness import cells
+    from run import Context
+
+    fusion = "%fusion.3 = bf16[8,1536]{1,0} fusion(%a.1), kind=kLoop, calls=%f"
+    loop = "%while.4 = (s32[], bf16[8,1536]{1,0}) while(%t.1), condition=%c, body=%b"
+    # chip 0: an all-reduce that a fusion overlaps for half its length,
+    # both inside a loop whose own event spans them
+    d0 = T.Device("/device:TPU:0", [loop, fusion, _hlo("all-reduce-start", "all-reduce-start")],
+                  np.array([[0, 150], [0, 100], [50, 150]], float), ["m"],
+                  np.array([[0, 150]], float))
+    # chip 1: an all-gather alone, a collective-permute-done under a
+    # fusion, and a fusion named after a collective, which is no collective
+    d1 = T.Device("/device:TPU:1", [_hlo("all-gather", "all-gather"), fusion,
+                                    _hlo("collective-permute-done", "collective-permute-done"),
+                                    _hlo("all-reduce-scatter-fusion", "fusion")],
+                  np.array([[0, 80], [100, 200], [120, 140], [300, 400]], float), ["m"],
+                  np.array([[0, 400]], float))
+    ctx = Context(trace=T.Trace([d0, d1], window_s=1e-6))
+    reader = cells.load_reader(benchtiny.BENCH_DIR, "collective_share.train")
+    # chip 0 exposes 50 of its 100 ns, chip 1 all 80 of its 80 (+20 hidden)
+    assert reader.read(ctx) == pytest.approx(8.0)
+    assert "worst /device:TPU:1" in ctx.notes[0]
+    alone = Context(trace=T.Trace([d0], window_s=1e-6))
+    assert reader.read(alone) == pytest.approx(5.0)
+    none = Context(trace=T.Trace([_device(["a"], [[0, 10]])], window_s=1e-6))
+    assert reader.read(none) is None
